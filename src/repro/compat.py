@@ -1,34 +1,37 @@
-"""Version-compat shims for jax API drift.
+"""The one import point for the jax surface outside ``repro.models``.
 
-The codebase targets the current jax surface (``jax.shard_map``,
-``pallas.tpu.CompilerParams``); older installed versions ship the same
-functionality under the pre-promotion names (``jax.experimental.shard_map``
-with ``check_rep``, ``TPUCompilerParams``). These wrappers resolve whichever
-spelling exists at import time so kernels and collectives run on both.
+Modules outside the jax-containment allowlist (see the ``jax-containment``
+rule of :mod:`repro.core.warpsim.lint`) bind jax through here, so new jax
+surface is reviewed in one place. Also home to the process-level jax
+settings every entry point shares: the persistent compilation cache and
+the "is a backend up?" check that keeps forked pools away from a process
+that holds a device.
 """
 
 from __future__ import annotations
 
+import os
+
 import jax
+
+#: Fixed in-checkout compile-cache directory, used when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset. The path is part of the cache
+#: key, so it never depends on a tempdir, a pid or the time.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 
 def shard_map(f, mesh, in_specs, out_specs, **kw):
-    """``jax.shard_map`` with fallback to ``jax.experimental.shard_map``."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        if "check_vma" in kw:  # kwarg renamed from check_rep at promotion
-            kw["check_rep"] = kw.pop("check_vma")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    """``jax.shard_map`` with keyword mesh/specs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)
 
 
 def tpu_compiler_params(**kw):
-    """``pltpu.CompilerParams`` / legacy ``pltpu.TPUCompilerParams``."""
+    """``pltpu.CompilerParams`` (import deferred to call)."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kw)
+    return pltpu.CompilerParams(**kw)
 
 
 def pallas():
@@ -43,35 +46,32 @@ def jax_modules():
     Modules outside the jax-containment allowlist (``compat.py``,
     ``warpsim/_pallas.py`` — see the ``jax-containment`` rule of
     :mod:`repro.core.warpsim.lint`) must not ``import jax`` directly;
-    they bind the modules from here instead, so version-drift shims keep
-    one choke point and new jax surface is reviewed in one place.
+    they bind the modules from here instead, so new jax surface is
+    reviewed in one place.
     """
     import jax.numpy
     import jax.sharding
     return jax, jax.numpy, jax.sharding
 
 
-def enable_x64():
-    """Context manager scoping 64-bit jax types to the enclosed block.
+def init_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; return its directory.
 
-    The warpsim timing model is IEEE-754 double arithmetic; the rest of the
-    repo's kernels run the jax default (f32). Scoping x64 keeps the two from
-    interfering — a global ``jax_enable_x64`` update would change dtypes
-    under every other jit in the process.
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it and
+    nothing else is set here. Otherwise the cache goes to the fixed
+    :data:`COMPILE_CACHE_DIR` inside the checkout. Call before the first
+    compile of the process.
     """
-    import jax.experimental as _jexp
-    ctx = getattr(_jexp, "enable_x64", None)
-    if ctx is not None:
-        return ctx()
-    import contextlib
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
-    @contextlib.contextmanager
-    def _fallback():
-        old = jax.config.jax_enable_x64
-        jax.config.update("jax_enable_x64", True)
-        try:
-            yield
-        finally:
-            jax.config.update("jax_enable_x64", old)
 
-    return _fallback()
+def backend_initialized() -> bool:
+    """True once this process has brought up a jax backend (and so may
+    hold a device): forking it then risks a deadlock, or a child that
+    fights the parent for the chip."""
+    from jax._src import xla_bridge
+    return xla_bridge.backends_are_initialized()

@@ -31,8 +31,8 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 # jax-containment (warpsim-lint): repro.core modules bind jax through the
-# compat choke point instead of importing it — version-drift shims stay
-# in one reviewed place.
+# compat choke point instead of importing it — new jax surface stays in
+# one reviewed place.
 from repro import compat
 from repro.models import moe as moe_mod
 from repro.models.config import ModelConfig

@@ -25,10 +25,10 @@ Timing engines (``simulate(..., engine=...)`` — all bit-identical):
     native        compiled C scheduling loop (~25x event)
     fast          flat-CSR numpy/heapq loop (always available)
     fast_nested   previous-generation fast path, benchmark baseline
-    pallas        JAX/Pallas device core; sweeps batch a whole trace
+    pallas        JAX device core; sweeps batch a whole trace
                   family (all expansion keys x machine variants of
-                  one ThreadTrace) into ONE launch; falls back to
-                  fast when jax is missing or WARPSIM_PALLAS=0
+                  one ThreadTrace) into ONE launch; runs fast only
+                  under WARPSIM_PALLAS=0 (device failures raise)
     event         reference event loop (the model's ground truth)
     ============= ===================================================
 

@@ -1,4 +1,4 @@
-"""JAX/Pallas trace-family timing core (``engine="pallas"``).
+"""JAX trace-family timing core (``engine="pallas"``).
 
 One device launch simulates an entire *trace family*: every (expansion key,
 machine variant) pair derived from one :class:`ThreadTrace`. Each pair is a
@@ -6,28 +6,40 @@ machine variant) pair derived from one :class:`ThreadTrace`. Each pair is a
 scalars — and all units of a launch are padded to shared power-of-two
 shapes, stacked on a leading axis and run under one ``jax.vmap`` inside one
 ``jax.jit`` call. The per-block machine mapping (memory controller, L1 set
-index, store service occupancy) is computed on device by a Pallas kernel
-(``interpret=True`` off-TPU, following :mod:`repro.kernels.ops`); the
-scheduling recurrence itself — inherently sequential in simulated time — is
-a ``lax.while_loop`` over the CSR op columns in the same launch, with the
-ready-warp min-heap recast as a masked ``argmin`` over the per-warp ready
-times (first-minimum index == heapq's lowest-warp-id tie-break).
+index) is computed in the same jit; the scheduling recurrence itself —
+inherently sequential in simulated time — is a ``lax.while_loop`` over the
+CSR op columns, with the ready-warp min-heap recast as a masked ``argmin``
+over the per-warp ready times (first-minimum index == heapq's lowest-warp-id
+tie-break).
 
-Bit-identity with the reference event loop is preserved the same way the C
-core preserves it: the device program performs the *same IEEE-754 double
-operations in the same order* (x64 is scoped via
-:func:`repro.compat.enable_x64`) and replays the identical decision
-sequence — argmin pop order, LRU eviction by unique touch tick, pending-line
-fill minimum, SW+ merge window. The SW+ outstanding table becomes a dense
+**Number format.** The reference engines compute simulated time in IEEE-754
+doubles. XLA:TPU has no f64 unit: it lowers f64 to a pair of f32s, which
+is neither IEEE nor bit-identical. So the device program holds no floats at
+all. Every time value is the int64 *bit pattern* of its (non-negative)
+double. For non-negative doubles the bit patterns order exactly like the
+values, so ``max``/``min``/comparisons/``argmin`` run on the integers
+unchanged; the only arithmetic the recurrence needs is addition of two
+non-negative doubles, done by :func:`_f64_add` — an exact round-to-nearest-
+even add on the bit patterns. The one product in the model (store service
+occupancy ``svc_unit * (max(nbytes, 32) / 64.0)``) is precomputed on the
+host in numpy doubles. Integer ops are exact on every backend, so the
+device replays the reference engine's arithmetic bit for bit on CPU and
+TPU alike. 64-bit integer types are scoped to the launch with the
+thread-local ``jax.enable_x64(True)``.
+
+The same decision sequence as the reference event loop is replayed —
+argmin pop order, LRU eviction by unique touch tick, pending-line fill
+minimum, SW+ merge window. The SW+ outstanding table becomes a dense
 ``[n_sms, n_unique_blocks]`` array (exact: the dict's >4096-entry prune only
 drops entries that can never merge again, so *any* exact map is
 equivalent). The golden + hypothesis tests in ``tests/test_golden.py``
 assert ``pallas == native == fast == event`` on every field.
 
-Gating mirrors :mod:`._native`: ``WARPSIM_PALLAS=0`` (re-read on every
-call, so a live daemon can be disabled without restart), jax import
-failure, or a failed probe all make :func:`available` return False and
-callers fall back to the flat-CSR engines. ``engine="auto"`` never selects
+Gating: ``WARPSIM_PALLAS=0`` (re-read on every call, so a live daemon can
+be disabled without restart) makes :func:`available` return False and the
+entry points return None; callers then run the flat-CSR engines. That
+kill switch is the only fallback: a failed import, compile or launch
+raises with the underlying message. ``engine="auto"`` never selects
 pallas — on CPU hosts the XLA loop is far slower than the C core; the
 engine exists for accelerator-resident grids and must be asked for.
 
@@ -38,7 +50,7 @@ bench-smoke CI assert on it (a family must cost one launch, not N cells).
 from __future__ import annotations
 
 import functools
-import os
+import threading
 import warnings
 from typing import List, Optional, Sequence, Tuple
 
@@ -47,14 +59,21 @@ import numpy as np
 from repro.core.warpsim import envcfg
 
 # Completed device launches (one per simulated family batch), for the
-# one-launch-per-family assertions in tests and bench smoke.
+# one-launch-per-family assertions in tests and bench smoke. Daemon
+# threads launch concurrently, so increments take the lock.
 LAUNCHES = 0
+_LAUNCH_LOCK = threading.Lock()
 
-_modules_cache = None       # (jax, jnp, lax, pl) once imported
+_modules_cache = None       # (jax, jnp, lax) once imported
 _import_attempted = False
 _import_error: Optional[str] = None
 _probe_result: Optional[bool] = None
 _warned = False
+
+# Bit patterns of the doubles the device program needs.
+_F64_INF = 0x7FF0000000000000
+_MANT_MASK = (1 << 52) - 1
+_HIDDEN_BIT = 1 << 52
 
 
 def _env_disabled() -> bool:
@@ -75,25 +94,30 @@ def _modules():
         import jax.numpy as jnp
         from jax import lax
 
-        from repro import compat
-
-        pl = compat.pallas()
-        _modules_cache = (jax, jnp, lax, pl)
+        _modules_cache = (jax, jnp, lax)
     except Exception as e:  # jax missing / broken jaxlib
         _import_error = f"{e.__class__.__name__}: {e}"
         _modules_cache = None
     return _modules_cache
 
 
-def _warn_unavailable() -> None:
+def _require_modules():
+    """The jax modules, or raise why they are missing (never degrade)."""
+    mods = _modules()
+    if mods is None:
+        raise RuntimeError(
+            f"warpsim pallas engine: jax is unavailable ({_import_error})")
+    return mods
+
+
+def _warn_disabled() -> None:
     global _warned
     if _warned:
         return
     _warned = True
     warnings.warn(
-        "warpsim pallas engine unavailable, falling back to the flat-CSR "
-        f"engines for this process ({_import_error or 'unknown failure'})",
-        RuntimeWarning, stacklevel=3)
+        "warpsim pallas engine disabled by WARPSIM_PALLAS; running the "
+        "flat-CSR engines instead", RuntimeWarning, stacklevel=3)
 
 
 def available() -> bool:
@@ -114,7 +138,8 @@ def status(probe: bool = False) -> dict:
 
     ``enabled`` re-reads ``WARPSIM_PALLAS`` at call time. With
     ``probe=True`` a one-op family is actually simulated, so the report
-    states whether the device path is live rather than merely importable.
+    states whether the device path is live rather than merely importable;
+    a failed probe leaves the compiler's message in ``error``.
     """
     global _probe_result
     enabled = not _env_disabled()
@@ -153,6 +178,8 @@ def _self_probe() -> bool:
         cycles = float(out[0][0])
         return bool(np.isfinite(cycles) and cycles > 0.0)
     except Exception as e:
+        # Reported, not swallowed: /healthz shows the message and the
+        # engine as unavailable; cells asked for pallas still raise.
         _import_error = f"probe failed: {e.__class__.__name__}: {e}"
         return False
 
@@ -168,6 +195,33 @@ def _pow2(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
+def _f64_add(jnp, a, b):
+    """``a + b`` for int64 bit patterns of finite non-negative doubles.
+
+    Exact IEEE-754 round-to-nearest-even, subnormals included, overflow to
+    +inf: align the smaller significand with three extra bits (guard,
+    round, sticky), add, renormalize by at most one bit, round. Writing
+    the result as ``((e - 1) << 52) + significand`` lets a significand
+    carry (hidden bit, or rounding up to 2**53) step the exponent for free.
+    """
+    hi = jnp.maximum(a, b)
+    lo = jnp.minimum(a, b)
+    e_hi = hi >> 52
+    e_lo = lo >> 52
+    m_hi = ((hi & _MANT_MASK) | jnp.where(e_hi > 0, _HIDDEN_BIT, 0)) << 3
+    m_lo = ((lo & _MANT_MASK) | jnp.where(e_lo > 0, _HIDDEN_BIT, 0)) << 3
+    e_hi = jnp.maximum(e_hi, 1)         # subnormals share exponent 1
+    shift = jnp.minimum(e_hi - jnp.maximum(e_lo, 1), 60)
+    sticky = (m_lo & ((jnp.ones_like(m_lo) << shift) - 1)) != 0
+    s = m_hi + ((m_lo >> shift) | sticky)
+    carry = s >> 56                     # 0 or 1: sum reached 2**56
+    s = (s >> carry) | (s & carry)
+    m = s >> 3
+    rest = s & 7
+    m = m + ((rest > 4) | ((rest == 4) & ((m & 1) == 1)))
+    return jnp.minimum(((e_hi + carry - 1) << 52) + m, _F64_INF)
+
+
 @functools.lru_cache(maxsize=64)
 def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
                 ways_pad: int, n_slots_pad: int):
@@ -175,58 +229,14 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
 
     Array-shape buckets (warps / ops / blocks / units) are handled by jit's
     own shape-keyed cache; the L1 / DRAM / outstanding state dimensions are
-    python ints baked into the trace, so they key this cache.
+    python ints baked into the trace, so they key this cache. The program
+    is integer-only (see the module docstring), so one build serves every
+    backend; the caller lowers it for whichever device it targets.
     """
-    jax, jnp, lax, pl = _modules()
-    interpret = jax.default_backend() != "tpu"
-    f64 = jnp.float64
+    jax, jnp, lax = _require_modules()
     i64 = jnp.int64
-    INF = jnp.inf
-
-    # ---- Pallas block-prep kernel: per-block machine mapping -------------
-    # One grid step per unit; each step maps that unit's whole block pool
-    # to its memory controller, L1 set index and store-transaction service
-    # occupancy (the "aggregate_stream on device" piece — the expansion
-    # itself is cached host-side and shared across the family).
-
-    def _prep_kernel(blocks_ref, nb_ref, nctrl_ref, nsets_ref, svc_ref,
-                     ctrl_ref, si_ref, ssvc_ref):
-        b = blocks_ref[...]
-        nb = nb_ref[...]
-        nctrl = nctrl_ref[0, 0]
-        nsets = nsets_ref[0, 0]
-        svc = svc_ref[0, 0]
-        ctrl_ref[...] = b % nctrl
-        si_ref[...] = b % nsets
-        # Minimum 32 B burst, exactly the host expression:
-        # svc_unit * (max(nbytes, 32) / 64.0)
-        ssvc_ref[...] = svc * (jnp.maximum(nb, 32).astype(f64) / 64.0)
-
-    def _prep(blocks, nbytes, nctrl1, nsets1, svc1):
-        u, p = blocks.shape
-        row = lambda i: (i, 0)  # noqa: E731
-        return pl.pallas_call(
-            _prep_kernel,
-            grid=(u,),
-            in_specs=[
-                pl.BlockSpec((1, p), row),
-                pl.BlockSpec((1, p), row),
-                pl.BlockSpec((1, 1), row),
-                pl.BlockSpec((1, 1), row),
-                pl.BlockSpec((1, 1), row),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, p), row),
-                pl.BlockSpec((1, p), row),
-                pl.BlockSpec((1, p), row),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((u, p), i64),
-                jax.ShapeDtypeStruct((u, p), i64),
-                jax.ShapeDtypeStruct((u, p), f64),
-            ],
-            interpret=interpret,
-        )(blocks, nbytes, nctrl1, nsets1, svc1)
+    INF = _F64_INF
+    add = functools.partial(_f64_add, jnp)
 
     # ---- Scheduling recurrence for one unit ------------------------------
 
@@ -239,9 +249,10 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
         off_col = cols["off"]
         len_col = cols["len"]
         slot_col = cols["slot"]
-        ctrl_col = cols["ctrl"]
-        si_col = cols["si"]
         ssvc_col = cols["ssvc"]
+        # Per-block machine mapping: memory controller and L1 set index.
+        ctrl_col = cols["blocks"] % cols["nctrl"][0]
+        si_col = cols["blocks"] % cols["nsets"][0]
         ideal = cols["ideal"][0]
         hit_lat = cols["hit_lat"][0]
         depth = cols["depth"][0]
@@ -252,24 +263,25 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
         way_mask = jnp.arange(ways_pad, dtype=i64) < ways
         tick_inf = jnp.iinfo(i64).max
 
-        ready0 = jnp.where(next0 < op_end, 0.0, INF).astype(f64)
+        ready0 = jnp.where(next0 < op_end, 0, INF).astype(i64)
         state0 = (
             ready0,
             next0,
-            jnp.zeros((n_sms_pad,), f64),                       # issue_free
-            jnp.zeros((nctrl_pad,), f64),                       # ctrl_free
+            jnp.zeros((n_sms_pad,), i64),                       # issue_free
+            jnp.zeros((nctrl_pad,), i64),                       # ctrl_free
             jnp.full((n_sms_pad, n_sets_pad, ways_pad), -1, i64),   # tags
             jnp.zeros((n_sms_pad, n_sets_pad, ways_pad), i64),      # ticks
-            jnp.zeros((n_sms_pad, n_sets_pad, ways_pad), f64),      # fills
+            jnp.zeros((n_sms_pad, n_sets_pad, ways_pad), i64),      # fills
             jnp.zeros((n_sms_pad,), i64),                       # tick ctr
-            jnp.full((n_sms_pad, n_slots_pad), -INF, f64),      # outstanding
+            # Outstanding SW+ completions; -1 sorts below every time.
+            jnp.full((n_sms_pad, n_slots_pad), -1, i64),
             jnp.zeros((), i64),                                 # offchip
             jnp.zeros((), i64),                                 # merged
             jnp.zeros((), i64),                                 # l1 hits
         )
 
         def cond(st):
-            return jnp.any(jnp.isfinite(st[0]))
+            return jnp.any(st[0] < INF)
 
         def body(st):
             (ready, next_idx, issue_free, ctrl_free, tags, ticks, fills,
@@ -281,7 +293,7 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
             sm = sm_of[w]
             i = next_idx[w]
             t_start = jnp.maximum(ready_t, issue_free[sm])
-            t_acc = t_start + issue_col[i]
+            t_acc = add(t_start, issue_col[i])
             issue_free = issue_free.at[sm].set(t_acc)
             o = off_col[i]
             n_blk = len_col[i]
@@ -290,7 +302,7 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
                         off_n, mrg_n, hit_n)
 
             def compute_op(s):
-                return (t_acc + depth,) + s
+                return (add(t_acc, depth),) + s
 
             def load_op(s):
                 (ctrl_free, tags, ticks, fills, tickc, outst,
@@ -320,9 +332,9 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
                     # DRAM request (full 64 B read transaction).
                     cf = ctrl_free[b_ctrl]
                     start = jnp.maximum(cf, t_acc)
-                    completion = start + dram_lat + svc_unit
+                    completion = add(add(start, dram_lat), svc_unit)
                     ctrl_free = ctrl_free.at[b_ctrl].set(
-                        jnp.where(do_dram, start + svc_unit, cf))
+                        jnp.where(do_dram, add(start, svc_unit), cf))
                     # L1 fill / pending-line allocation.
                     tick = tick + do_dram.astype(i64)
                     valid = (row != -1) & way_mask
@@ -359,7 +371,7 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
                 (done, ctrl_free, tags, ticks, fills, tick, outst,
                  off_n, mrg_n, hit_n) = lax.fori_loop(
                     0, n_blk, blk,
-                    (t_acc + hit_lat, ctrl_free, tags, ticks, fills,
+                    (add(t_acc, hit_lat), ctrl_free, tags, ticks, fills,
                      tickc[sm], outst, off_n, mrg_n, hit_n))
                 tickc2 = tickc.at[sm].set(tick)
                 return (done, ctrl_free, tags, ticks, fills, tickc2,
@@ -373,10 +385,11 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
                     bi = o + j
                     cf = cfree[ctrl_col[bi]]
                     start = jnp.maximum(cf, t_acc)
-                    return cfree.at[ctrl_col[bi]].set(start + ssvc_col[bi])
+                    return cfree.at[ctrl_col[bi]].set(
+                        add(start, ssvc_col[bi]))
 
                 ctrl_free = lax.fori_loop(0, n_blk, blk, ctrl_free)
-                return (t_acc + hit_lat, ctrl_free, tags, ticks, fills,
+                return (add(t_acc, hit_lat), ctrl_free, tags, ticks, fills,
                         tickc, outst, off_n + n_blk, mrg_n, hit_n)
 
             (warp_ready, ctrl_free, tags, ticks, fills, tickc, outst,
@@ -394,17 +407,7 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
         issue_free = final[2]
         return (jnp.max(issue_free), final[9], final[10], final[11])
 
-    def _family_fn(cols):
-        ctrl, si, ssvc = _prep(cols["blocks"], cols["nbytes"],
-                               cols["nctrl1"], cols["nsets1"],
-                               cols["svc1"])
-        core = dict(cols)
-        core["ctrl"] = ctrl
-        core["si"] = si
-        core["ssvc"] = ssvc
-        return jax.vmap(_simulate_one)(core)
-
-    return jax.jit(_family_fn)
+    return jax.jit(jax.vmap(_simulate_one))
 
 
 # ---------------------------------------------------------------------------
@@ -440,27 +443,29 @@ def _cfg_scalars(cfg) -> dict:
     )
 
 
-def _launch_units(units: Sequence[Tuple[dict, dict]],
-                  count_launch: bool = True) -> List[Tuple]:
-    """Pad, stack and simulate units = [(stream cols, machine scalars)].
+def _bits(x) -> np.ndarray:
+    """int64 bit patterns of float64 values (the device's time format)."""
+    return np.asarray(x, dtype=np.float64).view(np.int64)
 
-    One jit call per invocation — the family-launch unit the sweep layer
-    and CI assert on. Returns ``(raw_cycles, offchip, merged, l1_hits)``
-    per unit, in order.
+
+def pack_units(units: Sequence[Tuple[dict, dict]]) -> Tuple[tuple, dict]:
+    """Pad and stack units = [(stream cols, machine scalars)].
+
+    Returns ``(state_dims, stacked)``: the ``_get_launch`` bucket key and
+    the dict of ``[u_pad, ...]`` int arrays one launch consumes. Times
+    are float64 bit patterns; the store service occupancy is computed
+    here with the host's expression, ``svc_unit * (max(nbytes, 32) /
+    64.0)``.
     """
-    global LAUNCHES
-    jax, jnp, lax, _pl = _modules()
-    from repro import compat
-
     n_units = len(units)
     u_pad = _pow2(n_units)
     w_pad = _pow2(max(c["n_warps"] for c, _ in units))
     ops_pad = _pow2(max(len(c["issue"]) for c, _ in units))
     blk_pad = _pow2(max(len(c["blocks"]) for c, _ in units))
-    sms_pad = _pow2(max(s["num_sms"] for _, s in units))
-    ctrl_pad = _pow2(max(s["num_mem_ctrls"] for _, s in units))
-    sets_pad = _pow2(max(s["n_sets"] for _, s in units))
-    ways_pad = _pow2(max(s["ways"] for _, s in units))
+    dims = (_pow2(max(s["num_sms"] for _, s in units)),
+            _pow2(max(s["num_mem_ctrls"] for _, s in units)),
+            _pow2(max(s["n_sets"] for _, s in units)),
+            _pow2(max(s["ways"] for _, s in units)))
 
     # SW+ outstanding table: dense over the unique blocks of each stream.
     # Cache the remap per stream object — variants share their expansion.
@@ -478,70 +483,69 @@ def _launch_units(units: Sequence[Tuple[dict, dict]],
     for cols, _ in units:
         s = slots_of(cols)
         n_slots = max(n_slots, int(s.max(initial=0)) + 1)
-    slots_pad = _pow2(n_slots)
+    dims += (_pow2(n_slots),)
 
-    def stack(name, dtype, pad_width, fill=0):
-        outv = np.full((u_pad, pad_width), fill, dtype=dtype)
-        return outv
+    def stack(width, fill=0):
+        return np.full((u_pad, width), fill, dtype=np.int64)
 
-    next0 = stack("next0", np.int64, w_pad)
-    end = stack("end", np.int64, w_pad)
-    sm_of = stack("sm_of", np.int64, w_pad)
-    issue = stack("issue", np.float64, ops_pad)
-    kind = stack("kind", np.int32, ops_pad)
-    off = stack("off", np.int64, ops_pad)
-    length = stack("len", np.int64, ops_pad)
-    blocks = stack("blocks", np.int64, blk_pad)
-    nbytes = stack("nbytes", np.int64, blk_pad, fill=64)
-    slot = stack("slot", np.int64, blk_pad)
-    ideal = np.zeros((u_pad, 1), dtype=bool)
-    hit_lat = np.zeros((u_pad, 1), dtype=np.float64)
-    depth = np.zeros((u_pad, 1), dtype=np.float64)
-    dram_lat = np.zeros((u_pad, 1), dtype=np.float64)
-    svc1 = np.ones((u_pad, 1), dtype=np.float64)
-    ways = np.ones((u_pad, 1), dtype=np.int64)
-    nctrl1 = np.ones((u_pad, 1), dtype=np.int64)
-    nsets1 = np.ones((u_pad, 1), dtype=np.int64)
+    st = {k: stack(w_pad) for k in ("next0", "end", "sm_of")}
+    st.update({k: stack(ops_pad) for k in ("issue", "off", "len")})
+    st["kind"] = np.zeros((u_pad, ops_pad), dtype=np.int32)
+    st.update({k: stack(blk_pad) for k in ("blocks", "slot", "ssvc")})
+    st["ideal"] = np.zeros((u_pad, 1), dtype=bool)
+    st.update({k: stack(1) for k in ("hit_lat", "depth", "dram_lat", "svc")})
+    # Padding units have no warps; 1 keeps their block modulo defined.
+    st.update({k: stack(1, fill=1) for k in ("ways", "nctrl", "nsets")})
 
     for u, (cols, scal) in enumerate(units):
         nw = cols["n_warps"]
         n_sms = scal["num_sms"]
-        next0[u, :nw] = cols["op_start"][:nw]
-        end[u, :nw] = cols["op_start"][1:nw + 1]
+        st["next0"][u, :nw] = cols["op_start"][:nw]
+        st["end"][u, :nw] = cols["op_start"][1:nw + 1]
         wids = np.arange(nw, dtype=np.int64)
-        sm_of[u, :nw] = np.minimum(wids * n_sms // max(nw, 1), n_sms - 1)
+        st["sm_of"][u, :nw] = np.minimum(wids * n_sms // max(nw, 1),
+                                         n_sms - 1)
         no = len(cols["issue"])
-        issue[u, :no] = cols["issue"]
-        kind[u, :no] = cols["kind"]
-        off[u, :no] = cols["blk_off"]
-        length[u, :no] = cols["blk_len"]
+        st["issue"][u, :no] = _bits(cols["issue"])
+        st["kind"][u, :no] = cols["kind"]
+        st["off"][u, :no] = cols["blk_off"]
+        st["len"][u, :no] = cols["blk_len"]
         nb = len(cols["blocks"])
-        blocks[u, :nb] = cols["blocks"]
-        nbytes[u, :nb] = cols["nbytes"]
-        slot[u, :nb] = slots_of(cols)
-        ideal[u, 0] = scal["ideal"]
-        hit_lat[u, 0] = scal["hit_lat"]
-        depth[u, 0] = scal["depth"]
-        dram_lat[u, 0] = scal["dram_lat"]
-        svc1[u, 0] = scal["svc_unit"]
-        ways[u, 0] = scal["ways"]
-        nctrl1[u, 0] = scal["num_mem_ctrls"]
-        nsets1[u, 0] = scal["n_sets"]
+        st["blocks"][u, :nb] = cols["blocks"]
+        st["slot"][u, :nb] = slots_of(cols)
+        # Minimum 32 B burst, exactly the host expression.
+        st["ssvc"][u, :nb] = _bits(
+            scal["svc_unit"] * (np.maximum(cols["nbytes"], 32) / 64.0))
+        st["ideal"][u, 0] = scal["ideal"]
+        for k in ("hit_lat", "depth", "dram_lat"):
+            st[k][u, 0] = _bits(scal[k])
+        st["svc"][u, 0] = _bits(scal["svc_unit"])
+        st["ways"][u, 0] = scal["ways"]
+        st["nctrl"][u, 0] = scal["num_mem_ctrls"]
+        st["nsets"][u, 0] = scal["n_sets"]
+    return dims, st
 
-    stacked = dict(
-        next0=next0, end=end, sm_of=sm_of, issue=issue, kind=kind,
-        off=off, len=length, blocks=blocks, nbytes=nbytes, slot=slot,
-        ideal=ideal, hit_lat=hit_lat, depth=depth, dram_lat=dram_lat,
-        svc=svc1, svc1=svc1, ways=ways, nctrl1=nctrl1, nsets1=nsets1,
-    )
 
-    launch = _get_launch(sms_pad, ctrl_pad, sets_pad, ways_pad, slots_pad)
-    with compat.enable_x64():
+def _launch_units(units: Sequence[Tuple[dict, dict]],
+                  count_launch: bool = True) -> List[Tuple]:
+    """Pack and simulate units = [(stream cols, machine scalars)].
+
+    One jit call per invocation — the family-launch unit the sweep layer
+    and CI assert on. Returns ``(raw_cycles, offchip, merged, l1_hits)``
+    per unit, in order. Compile and launch errors propagate.
+    """
+    global LAUNCHES
+    jax, _jnp, _lax = _require_modules()
+    dims, stacked = pack_units(units)
+    launch = _get_launch(*dims)
+    with jax.enable_x64(True):
         cycles, offchip, merged, hits = jax.device_get(launch(stacked))
     if count_launch:
-        LAUNCHES += 1
+        with _LAUNCH_LOCK:
+            LAUNCHES += 1
+    cycles = np.asarray(cycles, dtype=np.int64).view(np.float64)
     return [(float(cycles[u]), int(offchip[u]), int(merged[u]),
-             int(hits[u])) for u in range(n_units)]
+             int(hits[u])) for u in range(len(units))]
 
 
 # ---------------------------------------------------------------------------
@@ -553,13 +557,12 @@ def run_scheduling_loop(n_warps: int, op_start, issue, kind, blk_off,
                         blk_len, blocks, nbytes, cfg):
     """Single-cell device run; mirrors ``_native.run_scheduling_loop``.
 
-    Returns ``(raw_cycles, offchip, merged, l1_hits)`` or None when the
-    engine is unavailable or the launch fails (callers fall back to the
-    flat-CSR engine).
+    Returns ``(raw_cycles, offchip, merged, l1_hits)``, or None when
+    ``WARPSIM_PALLAS`` is off (callers then run the flat-CSR engine). A
+    missing jax or a failed compile/launch raises.
     """
-    global _import_error
-    if _modules() is None:
-        _warn_unavailable()
+    if _env_disabled():
+        _warn_disabled()
         return None
     cols = dict(
         n_warps=int(n_warps),
@@ -571,12 +574,7 @@ def run_scheduling_loop(n_warps: int, op_start, issue, kind, blk_off,
         blocks=np.asarray(blocks, dtype=np.int64),
         nbytes=np.asarray(nbytes, dtype=np.int64),
     )
-    try:
-        return _launch_units([(cols, _cfg_scalars(cfg))])[0]
-    except Exception as e:
-        _import_error = f"launch failed: {e.__class__.__name__}: {e}"
-        _warn_unavailable()
-        return None
+    return _launch_units([(cols, _cfg_scalars(cfg))])[0]
 
 
 def run_family(pairs):
@@ -585,14 +583,14 @@ def run_family(pairs):
     ``pairs`` is ``[(WarpStream, MachineConfig), ...]`` — every expansion
     key × machine variant of one ThreadTrace (streams may repeat across
     variants that share an expansion). Returns a list of
-    ``(raw_cycles, offchip, merged, l1_hits)`` in order, or None when the
-    engine is unavailable / the launch fails.
+    ``(raw_cycles, offchip, merged, l1_hits)`` in order, or None when
+    ``WARPSIM_PALLAS`` is off. A missing jax or a failed compile/launch
+    raises.
     """
-    global _import_error
     if not pairs:
         return []
-    if _modules() is None:
-        _warn_unavailable()
+    if _env_disabled():
+        _warn_disabled()
         return None
     col_cache: dict = {}
     units = []
@@ -601,9 +599,4 @@ def run_family(pairs):
         if cols is None:
             cols = col_cache[id(stream)] = _stream_cols(stream)
         units.append((cols, _cfg_scalars(cfg)))
-    try:
-        return _launch_units(units)
-    except Exception as e:
-        _import_error = f"launch failed: {e.__class__.__name__}: {e}"
-        _warn_unavailable()
-        return None
+    return _launch_units(units)

@@ -10,9 +10,8 @@ ratchet instead of eroding:
 ``jax-containment``
     ``import jax`` (any spelling) and use of an unbound ``jax`` name in
     ``repro/core/`` modules outside the allowlist (``compat.py``,
-    ``_pallas.py``). Version-drift shims (``jax.shard_map``,
-    ``pltpu.CompilerParams``) only work if the compat module is the one
-    choke point new jax surface flows through.
+    ``_pallas.py``). New jax surface is reviewed in one place only if
+    the compat module is the one choke point it flows through.
 ``typed-http-boundary``
     ``urllib.request.urlopen`` outside the two blessed transport
     wrappers (``work_queue._http_json``, ``benchmarks/service_smoke``),
@@ -351,7 +350,7 @@ def _check_jax(ctx: _FileContext) -> Iterator[Finding]:
                         ctx.path, node.lineno, "jax-containment",
                         f"direct 'import {alias.name}': bind jax through "
                         f"repro.compat (e.g. compat.jax_modules()) so "
-                        f"version-drift shims keep one choke point")
+                        f"new jax surface keeps one choke point")
         elif isinstance(node, ast.ImportFrom):
             mod = node.module or ""
             if node.level == 0 and (mod == "jax"

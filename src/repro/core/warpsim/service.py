@@ -1120,15 +1120,19 @@ class SweepService:
         # state, which like WARPSIM_NATIVE is re-read per call.
         pallas = _pallas.status(probe=(self.engine == "pallas"))
         engine = self.engine
-        if engine == "auto":
+        ok = True
+        if engine == "auto" or (engine == "pallas"
+                                and not pallas["enabled"]):
+            # auto, or the explicit WARPSIM_PALLAS=0 kill switch: cells
+            # run on the host engine, so report that one.
             engine = "native" if native["engine"] == "native" else "fast"
         elif engine == "pallas" and pallas["engine"] != "pallas":
-            # Configured for the device core but it can't run (no jax /
-            # WARPSIM_PALLAS=0 / failed probe): report the engine cells
-            # will actually use via the per-cell fallback.
-            engine = "native" if native["engine"] == "native" else "fast"
+            # Configured for the device core and it cannot run (no jax,
+            # failed probe): cells would raise, so say so — never report
+            # a host engine in its place. The reason is in pallas.error.
+            ok = False
         return {
-            "ok": True,
+            "ok": ok,
             "model": MODEL_VERSION,
             "engine": engine,
             "native": native,
@@ -1885,6 +1889,11 @@ def main(argv: Optional[List[str]] = None) -> None:
     ap.add_argument("--verbose", action="store_true",
                     help="log every request to stderr")
     args = ap.parse_args(argv)
+    if args.engine == "pallas":
+        # Host-engine daemons never import jax; a device daemon keeps its
+        # compiled family programs across restarts.
+        from repro import compat
+        compat.init_compile_cache()
 
     # mesh=False: the env path needs the self URL, which for an
     # ephemeral --port 0 only exists after bind — configure below.

@@ -75,6 +75,7 @@ import functools
 import hashlib
 import json
 import os
+import sys
 import tempfile
 import threading
 import warnings
@@ -817,10 +818,10 @@ def _run_family_pallas(fam_payloads: List[_GroupPayload],
     All expansion-key groups of the family (each carrying its machine
     variants) become units of one ``_pallas.run_family`` call — a family
     costs one launch instead of one engine run per cell. Returns
-    ``(per-group result lists, launched)``; ``(None, False)`` when the
-    device core is unavailable or the launch failed, in which case the
-    caller degrades to the per-group path (whose per-cell pallas dispatch
-    falls back to the flat engine).
+    ``(per-group result lists, launched)``; ``(None, False)`` only when
+    ``WARPSIM_PALLAS`` is off, in which case the caller runs the
+    per-group path (whose per-cell pallas dispatch honours the same kill
+    switch). A failed compile or launch raises.
     """
     groups = []
     pairs = []
@@ -870,6 +871,15 @@ def compute_cell(bench: str, cfg: MachineConfig,
     ops = stream.to_warp_ops() if engine == "event" else stream
     with obs_mod.stage("engine", engine=engine, bench=bench):
         return simulate(wl.name, ops, cfg, engine=engine)
+
+
+def _jax_backend_up() -> bool:
+    """True once this process has initialised a jax backend (checked
+    without importing jax into a process that never used it)."""
+    if "jax" not in sys.modules:
+        return False
+    from repro import compat
+    return compat.backend_initialized()
 
 
 def run_sweep(
@@ -1039,6 +1049,11 @@ def run_sweep_with_stats(
             # second core already wins.
             parallel = len(payloads) >= 4 and (
                 ncpu >= 4 or (ncpu > 1 and not cells_are_cheap))
+        if parallel and _jax_backend_up():
+            # One process per device: a fork of a process whose jax
+            # backend is up can deadlock, and a child cannot share the
+            # parent's chip. Such a process sweeps serially.
+            parallel = False
 
         def _scatter(members, group_res) -> None:
             for (cell, key), res in zip(members, group_res):

@@ -43,8 +43,8 @@ golden + hypothesis tests in ``tests/test_golden.py``):
   jitted ``lax.while_loop`` over the CSR columns, built to simulate an
   entire trace family (all expansion keys x machine variants) in one
   device launch when driven through the sweep layer. Opt-in only
-  (``WARPSIM_PALLAS=0`` kills it; unavailable hosts fall back to
-  ``fast``).
+  (``WARPSIM_PALLAS=0`` kills it and runs ``fast``; a missing jax or a
+  failed compile or launch raises).
 
 ``engine="auto"`` (default) picks ``native`` when the compiled core is
 available and ``fast`` otherwise — never ``pallas``: on CPU hosts the XLA
@@ -612,9 +612,8 @@ def _simulate_pallas(name: str, warp_ops: Ops, cfg: MachineConfig
     One cell is a one-unit family launch. The real win — one launch for a
     whole trace family — is driven by ``sweep.run_sweep_with_stats``,
     which batches every (expansion key x machine variant) of a workload
-    into a single ``_pallas.run_family`` call. Falls back to ``fast`` when
-    the device core is unavailable (no jax, ``WARPSIM_PALLAS=0``, or a
-    failed launch), mirroring the native engine's fallback.
+    into a single ``_pallas.run_family`` call. Runs ``fast`` only when
+    ``WARPSIM_PALLAS=0``; a device failure raises.
     """
     if isinstance(warp_ops, WarpStream):
         st = warp_ops

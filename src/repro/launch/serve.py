@@ -3,8 +3,9 @@
 A fixed pool of `--slots` decode slots runs one fused ``decode_step`` per
 iteration. Finished or empty slots are refilled from the request queue
 (continuous batching): each refill prefills the new prompt and splices its
-KV/state cache into the slot. Per-slot position bookkeeping keeps ragged
-prompts independent.
+KV/state cache, its cache positions and its write cursor into the slot.
+The cache keeps one position per row, so ragged prompts decode
+independently.
 
 CPU-scale demo:
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b --smoke \
@@ -22,27 +23,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compat
 from repro.configs import get_config, list_archs
 from repro.models import model as model_lib
 
 
 class Request:
-    def __init__(self, rid: int, prompt: np.ndarray, max_new: int):
+    """One prompt. With `keep_logits` the server also records the fp32
+    logits of each of its steps (prefill first) in `logits`."""
+
+    def __init__(self, rid: int, prompt: np.ndarray, max_new: int,
+                 keep_logits: bool = False):
         self.rid = rid
         self.prompt = prompt
         self.max_new = max_new
         self.generated: List[int] = []
         self.done = False
+        self.keep_logits = keep_logits
+        self.logits: List[np.ndarray] = []
 
 
 def _splice_cache(pool_cache, req_cache, slot: int):
-    """Copy a single-sequence prefill cache into batch slot `slot`."""
+    """Copy a single-sequence prefill cache into batch slot `slot`.
+
+    Layer-stacked state is (L, B, ...); the per-row cursor leaves
+    (``positions`` (B, Sc), ``index`` (B,)) are batch-first.
+    """
     def splice(pool, single):
-        if pool.ndim >= 2 and single.ndim == pool.ndim and \
-                single.shape[0] == pool.shape[0] and pool.ndim >= 3:
-            # (L, B, ...) layer-stacked per-sequence state
+        if pool.ndim >= 3:
             return pool.at[:, slot].set(single[:, 0])
-        return pool
+        return pool.at[slot].set(single[0])
     return jax.tree.map(splice, pool_cache, req_cache)
 
 
@@ -56,9 +66,6 @@ class BatchedServer:
         self.max_len = max_len
         self.active: List[Optional[Request]] = [None] * slots
         self.cache = model_lib.init_decode_cache(cfg, slots, max_len)
-        # Per-slot decode positions (the fused cache keeps one global
-        # cursor; per-slot masking uses slot positions).
-        self.slot_pos = np.zeros(slots, dtype=np.int64)
         self._decode = jax.jit(
             lambda p, t, c: model_lib.decode_step(p, self.cfg, t, c))
         self._prefill = jax.jit(
@@ -69,7 +76,8 @@ class BatchedServer:
             self.params, {"tokens": jnp.asarray(req.prompt[None, :])})
         self.cache = _splice_cache(self.cache, rcache, slot)
         self.active[slot] = req
-        self.slot_pos[slot] = len(req.prompt)
+        if req.keep_logits:
+            req.logits.append(np.asarray(logits[0]))
         return int(jnp.argmax(logits[0]))
 
     def run(self, requests: List[Request]) -> dict:
@@ -89,11 +97,6 @@ class BatchedServer:
                 break
             # One fused decode step for all slots.
             toks = jnp.asarray(next_tokens[:, None])
-            if "kv" in self.cache:
-                # Align the global cursor with the max slot position; the
-                # position mask makes shorter slots correct.
-                self.cache["kv"]["index"] = jnp.asarray(
-                    int(self.slot_pos.max()), jnp.int32)
             logits, self.cache = self._decode(self.params, toks, self.cache)
             steps += 1
             nxt = np.asarray(jnp.argmax(logits, axis=-1))
@@ -102,7 +105,8 @@ class BatchedServer:
                     continue
                 req.generated.append(int(nxt[s]))
                 next_tokens[s] = int(nxt[s])
-                self.slot_pos[s] += 1
+                if req.keep_logits:
+                    req.logits.append(np.asarray(logits[s]))
                 if len(req.generated) >= req.max_new:
                     req.done = True
                     self.active[s] = None
@@ -124,6 +128,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compat.init_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     rng = np.random.default_rng(args.seed)

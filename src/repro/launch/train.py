@@ -28,7 +28,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import sharding
+from repro import compat, sharding
 from repro.checkpoint import ckpt
 from repro.configs import get_config, list_archs
 from repro.data import DataConfig, SyntheticCorpus
@@ -90,6 +90,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    compat.init_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     cfg = dataclasses.replace(cfg, remat="none") if args.smoke else cfg
@@ -119,12 +120,15 @@ def main(argv=None) -> dict:
         "opt": {"m": pspec, "v": pspec, "step": jax.sharding.PartitionSpec()},
     })
 
+    # Build the state directly in its shards: an eager init would first
+    # materialise every parameter and moment on one device.
+    init_sharded = jax.jit(init_state, out_shardings=state_sharding)
     start_step = 0
     if args.ckpt_dir:
         state, start_step = fault.resume_or_init(
-            args.ckpt_dir, init_state, shardings=state_sharding)
+            args.ckpt_dir, init_sharded, shardings=state_sharding)
     else:
-        state = jax.device_put(init_state(), state_sharding)
+        state = init_sharded()
 
     injector = fault.FailureInjector(
         args.fail_at,
@@ -175,12 +179,20 @@ def main(argv=None) -> dict:
     if saver:
         saver.save(args.steps, state)
         saver.wait()
+    # Where the trained parameters live: bytes of parameter shards per
+    # device id, read from the step's output shardings.
+    per_device: dict = {}
+    for leaf in jax.tree.leaves(state["params"]):
+        for shard in leaf.addressable_shards:
+            per_device[shard.device.id] = (per_device.get(shard.device.id, 0)
+                                           + shard.data.nbytes)
     result = {
         "first_loss": losses[0] if losses else None,
         "last_loss": losses[-1] if losses else None,
         "losses": losses,
         "straggler_events": len(monitor.events),
         "final_step": args.steps,
+        "param_bytes_per_device": dict(sorted(per_device.items())),
     }
     print(json.dumps({k: v for k, v in result.items() if k != "losses"}))
     return result

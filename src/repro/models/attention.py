@@ -146,16 +146,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
     """Per-layer stacked KV cache (plain dict so sharding/checkpoint rules
     can key on field names).
 
-    k, v: (L, B, S_cache, nkv, hd); positions: (S_cache,) (-1 = empty);
-    index: () next write cursor (monotone token position count).
+    k, v: (L, B, S_cache, nkv, hd); positions: (B, S_cache) (-1 = empty);
+    index: (B,) next write cursor per row (monotone token position
+    count), so rows of one batch may sit at different positions.
     For sliding-window configs S_cache == window and writes wrap (ring
     buffer); otherwise S_cache == max sequence length.
     """
     s_cache = min(max_len, cfg.sliding_window) if cfg.sliding_window else max_len
     shape = (cfg.n_layers, batch, s_cache, cfg.n_kv_eff, cfg.head_dim)
     cache = {
-        "positions": jnp.full((s_cache,), -1, jnp.int32),
-        "index": jnp.zeros((), jnp.int32),
+        "positions": jnp.full((batch, s_cache), -1, jnp.int32),
+        "index": jnp.zeros((batch,), jnp.int32),
     }
     if cfg.kv_cache_dtype == "int8":
         # Quantized KV: int8 payload + per-(token, head) bf16 scales
@@ -191,21 +192,22 @@ def decode_attention(params: dict, x: jax.Array, layer_k: jax.Array,
     """One-token attention against the cache for a single layer.
 
     x: (B, 1, D); layer_k/v: (B, S_cache, nkv, hd) *already updated* with
-    this step's k/v. Returns (B, 1, D).
+    this step's k/v; cache_positions: (B, S_cache); pos: (B,) each row's
+    position. Returns (B, 1, D).
     """
     b = x.shape[0]
     hd = cfg.head_dim
     nq, nkv = cfg.n_q_eff, cfg.n_kv_eff
     g = nq // nkv
-    q, _, _ = _project_qkv(params, x, pos[None].astype(jnp.int32), cfg)
+    q, _, _ = _project_qkv(params, x, pos[:, None].astype(jnp.int32), cfg)
     qh = (q.reshape(b, 1, nkv, g, hd).transpose(0, 2, 3, 1, 4)
           .astype(jnp.float32)) / (hd ** 0.5)           # (B,nkv,G,1,hd)
     s = jnp.einsum("bngqd,bknd->bngqk", qh,
                    layer_k.astype(jnp.float32))          # (B,nkv,G,1,Sc)
-    valid = (cache_positions >= 0) & (cache_positions <= pos)
+    valid = (cache_positions >= 0) & (cache_positions <= pos[:, None])
     if cfg.sliding_window is not None:
-        valid &= (pos - cache_positions) < cfg.sliding_window
-    s = jnp.where(valid[None, None, None, None, :], s, NEG_INF)
+        valid &= (pos[:, None] - cache_positions) < cfg.sliding_window
+    s = jnp.where(valid[:, None, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bngqk,bknd->bngqd", p, layer_v.astype(jnp.float32))
     out = out.transpose(0, 3, 1, 2, 4).reshape(b, 1, nq, hd).astype(x.dtype)
@@ -213,6 +215,6 @@ def decode_attention(params: dict, x: jax.Array, layer_k: jax.Array,
 
 
 def decode_kv(params: dict, x: jax.Array, pos: jax.Array, cfg: ModelConfig):
-    """Project this step's k, v for cache insertion. x: (B,1,D)."""
-    _, k, v = _project_qkv(params, x, pos[None].astype(jnp.int32), cfg)
+    """Project this step's k, v for cache insertion. x: (B,1,D); pos: (B,)."""
+    _, k, v = _project_qkv(params, x, pos[:, None].astype(jnp.int32), cfg)
     return k[:, 0], v[:, 0]        # (B, nkv, hd)

@@ -282,7 +282,8 @@ def prefill(params: dict, cfg: ModelConfig, batch: dict, max_len: int,
             jnp.arange(s - keep, s, dtype=jnp.int32))
         cache["kv"] = {k_: rows[k_] for k_ in rows
                        if k_ in ("k", "v", "k_scale", "v_scale")}
-        cache["kv"].update(positions=pos, index=jnp.asarray(s, jnp.int32))
+        cache["kv"].update(positions=jnp.broadcast_to(pos, (b, s_cache)),
+                           index=jnp.full((b,), s, jnp.int32))
     if sc is not None:
         cache["ssm"] = {"conv_x": rows["conv_x"], "conv_bc": rows["conv_bc"],
                         "h": rows["h"]}
@@ -294,7 +295,8 @@ def decode_step(params: dict, cfg: ModelConfig, token_or_embed: jax.Array,
                 ) -> Tuple[jax.Array, dict]:
     """One decode step.
 
-    token_or_embed: (B, 1) int32 tokens or (B, 1, D) embeddings.
+    token_or_embed: (B, 1) int32 tokens or (B, 1, D) embeddings. Each
+    row decodes at its own position (the cache's per-row ``index``).
     Returns (logits (B, V_pad) fp32, updated cache).
     """
     kv = cache.get("kv")
@@ -303,16 +305,19 @@ def decode_step(params: dict, cfg: ModelConfig, token_or_embed: jax.Array,
         x = params["embed"][token_or_embed]
     else:
         x = token_or_embed.astype(param_dtype(cfg))
+    b = x.shape[0]
     pos = (kv["index"] if kv is not None
-           else jnp.zeros((), jnp.int32))            # current position
+           else jnp.zeros((b,), jnp.int32))          # (B,) current positions
     if cfg.pos_emb == "sinusoidal":
-        pe = common.sinusoidal_pos_emb(pos[None], cfg.d_model)
-        x = x + pe[None].astype(x.dtype)
+        pe = common.sinusoidal_pos_emb(pos[:, None], cfg.d_model)
+        x = x + pe.astype(x.dtype)
 
+    rows_b = jnp.arange(b)
     if kv is not None:
         s_cache = kv["k"].shape[2]
         slot = (pos % s_cache).astype(jnp.int32)
-        new_positions = kv["positions"].at[slot].set(pos.astype(jnp.int32))
+        new_positions = kv["positions"].at[rows_b, slot].set(
+            pos.astype(jnp.int32))
     else:
         slot = new_positions = None
 
@@ -333,17 +338,17 @@ def decode_step(params: dict, cfg: ModelConfig, token_or_embed: jax.Array,
             if cfg.kv_cache_dtype == "int8":
                 k1q, k1s = attention.quantize_kv(k1)
                 v1q, v1s = attention.quantize_kv(v1)
-                new_row["k"] = row["k"].at[:, slot].set(k1q)
-                new_row["v"] = row["v"].at[:, slot].set(v1q)
-                new_row["k_scale"] = row["k_scale"].at[:, slot].set(k1s)
-                new_row["v_scale"] = row["v_scale"].at[:, slot].set(v1s)
+                new_row["k"] = row["k"].at[rows_b, slot].set(k1q)
+                new_row["v"] = row["v"].at[rows_b, slot].set(v1q)
+                new_row["k_scale"] = row["k_scale"].at[rows_b, slot].set(k1s)
+                new_row["v_scale"] = row["v_scale"].at[rows_b, slot].set(v1s)
                 layer_k = attention.dequantize_kv(
                     new_row["k"], new_row["k_scale"], param_dtype(cfg))
                 layer_v = attention.dequantize_kv(
                     new_row["v"], new_row["v_scale"], param_dtype(cfg))
             else:
-                layer_k = row["k"].at[:, slot].set(k1)
-                layer_v = row["v"].at[:, slot].set(v1)
+                layer_k = row["k"].at[rows_b, slot].set(k1)
+                layer_v = row["v"].at[rows_b, slot].set(v1)
                 new_row["k"], new_row["v"] = layer_k, layer_v
             a = attention.decode_attention(lp["attn"], hn, layer_k, layer_v,
                                            new_positions, pos, cfg)

@@ -183,6 +183,10 @@ def cache_specs(cache_struct, dp) -> dict:
             return P(None, dp, None, None)
         if leaf_key == "h":                   # (L, B, nh, P, N)
             return P(None, dp, TP, None, None)
+        if leaf_key == "positions":           # (B, Sc)
+            return P(dp, None)
+        if leaf_key == "index":               # (B,)
+            return P(dp)
         return P(*([None] * leaf.ndim))
 
     return jax.tree_util.tree_map_with_path(spec, cache_struct)

@@ -18,7 +18,7 @@ from repro.models.config import ModelConfig
 def test_granularity_binds_jax_through_compat():
     """jax-containment regression: granularity.py must not import jax
     directly — it binds the modules via ``compat.jax_modules()`` so
-    version-drift shims stay in one reviewed place."""
+    new jax surface stays in one reviewed place."""
     with open(granularity.__file__, encoding="utf-8") as fh:
         tree = ast.parse(fh.read())
     for node in ast.walk(tree):

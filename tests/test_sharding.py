@@ -112,4 +112,5 @@ def test_cache_specs_keys():
     specs = sharding.cache_specs(cache, "data")
     assert specs["kv"]["k"] == P(None, "data", None, "model", None)
     assert specs["ssm"]["h"] == P(None, "data", "model", None, None)
-    assert specs["kv"]["positions"] == P(None)
+    assert specs["kv"]["positions"] == P("data", None)
+    assert specs["kv"]["index"] == P("data")

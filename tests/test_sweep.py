@@ -785,3 +785,140 @@ def test_auto_engine_never_selects_pallas():
                                        parallel=False, engine="auto")
     assert stats["family_launches"] == 0
     assert _pallas.launch_count() == before
+
+
+@pallas_required
+def test_pallas_x64_scope_is_thread_local():
+    """Two threads run device launches while a third jits an f32
+    function: the launches stay exact and the third thread never sees
+    64-bit types (the x64 scope is per thread, not a process flag)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.warpsim.divergence import expand_stream
+    from repro.core.warpsim.timing import simulate
+    from repro.core.warpsim.trace import get_workload
+
+    cfg = machines.sw_plus()
+    wl = get_workload("DYN", n_threads=128)
+    stream = expand_stream(wl, cfg)
+    want = dataclasses.asdict(simulate(wl.name, stream, cfg, engine="fast"))
+    simulate(wl.name, stream, cfg, engine="pallas")     # compile once
+    f32 = jax.jit(lambda x: x * 2.0 + 1.0)
+    start = threading.Barrier(3)
+    errors, dtypes = [], set()
+
+    def launcher():
+        try:
+            start.wait()
+            for _ in range(4):
+                got = simulate(wl.name, stream, cfg, engine="pallas")
+                assert dataclasses.asdict(got) == want
+        except Exception as e:      # surfaced below
+            errors.append(e)
+
+    def plain():
+        try:
+            start.wait()
+            for i in range(40):
+                dtypes.add(f32(np.full(4, i, np.float32)).dtype)
+                dtypes.add(jnp.asarray(1.0).dtype)
+                assert jax.config.jax_enable_x64 is False
+        except Exception as e:
+            errors.append(e)
+
+    threads = [threading.Thread(target=launcher) for _ in range(2)]
+    threads.append(threading.Thread(target=plain))
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(300)
+    assert errors == []
+    assert dtypes == {np.dtype("float32")}
+
+
+@pallas_required
+def test_pallas_failed_launch_raises_instead_of_degrading(monkeypatch,
+                                                          tmp_path):
+    """A compile or launch failure surfaces with its message: no silent
+    host-engine fallback at the cell, sweep or service layer, and the
+    daemon's /healthz reports the failure instead of another engine."""
+    from repro.core.warpsim import _pallas
+    from repro.core.warpsim.divergence import expand_stream
+    from repro.core.warpsim.service import SweepService
+    from repro.core.warpsim.timing import simulate
+    from repro.core.warpsim.trace import get_workload
+
+    def broken(*_dims):
+        def launch(_stacked):
+            raise RuntimeError("Mosaic refused the kernel (injected)")
+        return launch
+
+    monkeypatch.setattr(_pallas, "_get_launch", broken)
+    monkeypatch.setattr(_pallas, "_probe_result", None)
+    monkeypatch.setattr(_pallas, "_import_error", None)
+    spec = _spec(benches=("DYN",))
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        run_sweep_with_stats(spec, parallel=False, engine="pallas")
+    cfg = machines.baseline(8)
+    wl = get_workload("DYN", n_threads=128)
+    with pytest.raises(RuntimeError, match="Mosaic refused"):
+        simulate(wl.name, expand_stream(wl, cfg), cfg, engine="pallas")
+    svc = SweepService(str(tmp_path), engine="pallas", persist_traces=False)
+    h = svc.healthz()
+    assert h["ok"] is False and h["engine"] == "pallas"
+    assert h["pallas"]["probed"] is False
+    assert "Mosaic refused" in h["pallas"]["error"]
+
+
+def test_no_fork_pool_once_jax_backend_is_up(monkeypatch):
+    """A process whose jax backend is initialised (it may hold the chip)
+    sweeps serially even when asked for a pool; without a backend the
+    pool is still used."""
+    import jax
+
+    jax.devices()                       # bring the backend up
+    assert sweep_mod._jax_backend_up() is True
+
+    class NoPool:
+        def __init__(self, *a, **kw):
+            raise AssertionError("process pool used after jax init")
+
+    monkeypatch.setattr(sweep_mod.concurrent.futures,
+                        "ProcessPoolExecutor", NoPool)
+    spec = _spec()
+    par = run_sweep(spec, parallel=True, max_workers=2)
+    serial = run_sweep(spec, parallel=False)
+    for m in serial:
+        for b in serial[m]:
+            assert (dataclasses.asdict(par[m][b])
+                    == dataclasses.asdict(serial[m][b]))
+    monkeypatch.setattr(sweep_mod, "_jax_backend_up", lambda: False)
+    with pytest.raises(AssertionError, match="process pool used"):
+        run_sweep(spec, parallel=True, max_workers=2)
+
+
+def test_compile_cache_dir_env_or_fixed_checkout_path(monkeypatch):
+    """The compile-cache helper honours JAX_COMPILATION_CACHE_DIR and
+    sets nothing; unset, it picks one fixed path inside the checkout."""
+    import jax
+
+    from repro import compat
+
+    old = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compat.init_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        first = compat.init_compile_cache()
+        assert first == compat.init_compile_cache() == \
+            compat.COMPILE_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == first
+        root = os.path.dirname(os.path.dirname(os.path.abspath(
+            sweep_mod.__file__)))
+        checkout = os.path.dirname(os.path.dirname(os.path.dirname(root)))
+        assert os.path.dirname(first) == checkout
+    finally:
+        jax.config.update("jax_compilation_cache_dir", old)
