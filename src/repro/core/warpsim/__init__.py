@@ -135,8 +135,24 @@ Serving runbook (the daemon fleet; full details in ROADMAP.md):
                            ``warpsim.obs`` registry — the same counters
                            ``/stats`` serves as the legacy dict, plus
                            ``warpsim_stage_seconds{stage=...}`` latency
-                           histograms (trace build, aggregate, engine,
-                           cache/peer/queue hops) and in-flight gauges.
+                           histograms and in-flight gauges. Stages:
+                           ``trace_build``, ``aggregate``, ``engine``;
+                           the device engine's ``pallas_pack`` (packing
+                           a launch), ``pallas_dispatch`` (the jit call)
+                           and its device holds, ``device_inflight``
+                           (hold time no earlier hold covered: sums to
+                           an upper bound on device busy time) and
+                           ``device_queued`` (the rest); ``cache_get``,
+                           ``cache_put``, ``peer_forward``,
+                           ``replicate``, ``worker.lease``/``renew``/
+                           ``complete``; ``server/<path>`` per handled
+                           request (``server/cell``, ``server/study``).
+                           In a process that called
+                           ``repro.compat.init_compile_cache()`` every
+                           stage, span and device hold also appears in
+                           profiler traces as ``warpsim.<stage>`` (the
+                           hold as ``warpsim.device``), on the device's
+                           clock.
     GET /debug/trace       span ring dump: ``?id=<trace>`` returns that
                            trace's spans (bounded ring, WARPSIM_OBS_RING
                            spans, default 2048 — oldest evicted); without

@@ -57,6 +57,7 @@ from typing import List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.warpsim import envcfg
+from repro.core.warpsim import obs as obs_mod
 
 # Completed device launches (one per simulated family batch), for the
 # one-launch-per-family assertions in tests and bench smoke. Daemon
@@ -532,14 +533,22 @@ def _launch_units(units: Sequence[Tuple[dict, dict]],
 
     One jit call per invocation — the family-launch unit the sweep layer
     and CI assert on. Returns ``(raw_cycles, offchip, merged, l1_hits)``
-    per unit, in order. Compile and launch errors propagate.
+    per unit, in order. Compile and launch errors propagate. Observed as
+    the ``pallas_pack`` stage (packing and the program lookup), one
+    ``device`` occupancy hold (``device_inflight`` / ``device_queued``)
+    and, inside it, the ``pallas_dispatch`` stage (the jit call alone).
     """
     global LAUNCHES
     jax, _jnp, _lax = _require_modules()
-    dims, stacked = pack_units(units)
-    launch = _get_launch(*dims)
-    with jax.enable_x64(True):
-        cycles, offchip, merged, hits = jax.device_get(launch(stacked))
+    with obs_mod.stage("pallas_pack"):
+        dims, stacked = pack_units(units)
+        launch = _get_launch(*dims)
+    # The hold runs from dispatch until the results are on the host, so
+    # its in-flight time bounds the device's busy time from above.
+    with obs_mod.occupancy("device", units=len(units)), jax.enable_x64(True):
+        with obs_mod.stage("pallas_dispatch"):
+            out = launch(stacked)
+        cycles, offchip, merged, hits = jax.device_get(out)
     if count_launch:
         with _LAUNCH_LOCK:
             LAUNCHES += 1

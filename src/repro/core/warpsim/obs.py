@@ -33,14 +33,31 @@ Span ``t0`` values are *monotonic-clock* readings local to one process:
 order spans within a process by them, across processes by parentage.
 
 **Stage profiling** — :func:`stage` wraps one cold-path stage
-(``trace_build``, ``aggregate``, ``engine``, ``pallas_family``,
-``cache_get``/``cache_put``, ``peer_forward``, ``replicate``,
-``worker.lease``/``renew``/``complete``): the duration is observed into
+(``trace_build``, ``aggregate``, ``engine``, ``pallas_pack``,
+``pallas_dispatch``, ``cache_get``/``cache_put``, ``peer_forward``,
+``replicate``, ``worker.lease``/``renew``/``complete``, and the
+daemon's ``server/<path>`` handlers): the duration is observed into
 the ambient registry's ``warpsim_stage_seconds{stage=...}`` histogram
 and, when a trace is active, recorded as a span. Overhead per stage is
-one clock read pair plus a dict append under a lock — tens of
-microseconds, negligible next to a cell simulation; ``WARPSIM_OBS=0``
-reduces every hook to a near-no-op for the paranoid.
+one clock read pair plus a dict append under a lock — microseconds,
+negligible next to a cell simulation; ``WARPSIM_OBS=0`` reduces every
+hook to a near-no-op for the paranoid.
+
+**Occupancy** — :func:`occupancy` wraps one hold of a shared serial
+resource (the device: one launch from dispatch until its results are
+back on the host). Each hold's length is split into the part no hold
+that ended before it covered (``<resource>_inflight``) and the rest
+(``<resource>_queued``), so the ``_inflight`` sum over a window is the
+length of the union of the holds: an upper bound on the resource's busy
+time, transfers included, and the window less it is time the resource
+certainly stood idle.
+
+**Profiler annotations** — once :func:`set_annotation_factory` has
+installed a factory (``repro.compat`` installs
+``jax.profiler.TraceAnnotation``), every stage, span and hold also
+enters an annotation named ``warpsim.<name>``, so a profiler trace shows
+the host stages on the same clock as the device's programs. This module
+itself imports no profiler and stays stdlib-only.
 
 Determinism stance: this module is deliberately **outside** the lint
 ``determinism`` scope (:data:`repro.core.warpsim.lint.DETERMINISM_MODULES`)
@@ -71,7 +88,8 @@ import time
 import uuid
 from collections import deque
 from typing import (
-    Callable, Dict, Iterator, List, Mapping, Optional, Sequence, Tuple,
+    Callable, ContextManager, Dict, Iterator, List, Mapping, Optional,
+    Sequence, Tuple,
 )
 
 from repro.core.warpsim import envcfg
@@ -99,6 +117,31 @@ DEFAULT_BUCKETS: Tuple[float, ...] = (
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
+
+
+#: Prefix of every profiler annotation this module enters.
+ANNOTATION_PREFIX = "warpsim."
+
+_NO_ANNOTATION = contextlib.nullcontext()
+_annotation_factory: Optional[Callable[[str], ContextManager]] = None
+
+
+def set_annotation_factory(factory: Optional[Callable[[str], ContextManager]]
+                           ) -> Optional[Callable[[str], ContextManager]]:
+    """Install `factory` (name -> context manager, such as
+    ``jax.profiler.TraceAnnotation``): every stage, span and occupancy
+    hold then also enters ``factory("warpsim.<name>")``. ``None``
+    removes it. Returns the factory it replaced."""
+    global _annotation_factory
+    prev, _annotation_factory = _annotation_factory, factory
+    return prev
+
+
+def _annotation(name: str) -> ContextManager:
+    factory = _annotation_factory
+    if factory is None:
+        return _NO_ANNOTATION
+    return factory(ANNOTATION_PREFIX + name)
 
 
 def enabled() -> bool:
@@ -767,19 +810,23 @@ def join_trace(trace_id: Optional[str], name: str,
 def span(name: str, **attrs) -> Iterator[Optional[TraceContext]]:
     """A child span under the ambient context (no-op without one).
     Nested spans/stages/events inside the body parent to this span."""
-    ctx = _CONTEXT.get()
-    if ctx is None or not ctx.recording or not enabled():
+    if not enabled():
         yield None
         return
-    child = TraceContext(ctx.trace_id, _new_span_id(), ctx.obs, True)
-    t0 = ctx.obs.clock()
-    token = _CONTEXT.set(child)
-    try:
-        yield child
-    finally:
-        _CONTEXT.reset(token)
-        _record(child, name, child.span_id, ctx.span_id, t0,
-                ctx.obs.clock() - t0, attrs)
+    ctx = _CONTEXT.get()
+    with _annotation(name):
+        if ctx is None or not ctx.recording:
+            yield None
+            return
+        child = TraceContext(ctx.trace_id, _new_span_id(), ctx.obs, True)
+        t0 = ctx.obs.clock()
+        token = _CONTEXT.set(child)
+        try:
+            yield child
+        finally:
+            _CONTEXT.reset(token)
+            _record(child, name, child.span_id, ctx.span_id, t0,
+                    ctx.obs.clock() - t0, attrs)
 
 
 def event(name: str, **attrs) -> None:
@@ -792,25 +839,100 @@ def event(name: str, **attrs) -> None:
 
 
 @contextlib.contextmanager
-def stage(name: str, **attrs) -> Iterator[None]:
+def stage(name: str, record_span: bool = True, **attrs) -> Iterator[None]:
     """Time one cold-path stage: observe the ambient domain's
     ``warpsim_stage_seconds{stage=name}`` histogram and, when a trace is
-    recording, append a span. This is the only clock the determinism
-    modules ever (indirectly) touch — their own source stays clock-free
-    and the lint rule keeps it that way."""
+    recording and `record_span` holds, append a span. This is the only
+    clock the determinism modules ever (indirectly) touch — their own
+    source stays clock-free and the lint rule keeps it that way."""
     if not enabled():
         yield
         return
     ctx = _CONTEXT.get()
     ob = ctx.obs if ctx is not None else default()
-    t0 = ob.clock()
-    try:
+    with _annotation(name):
+        t0 = ob.clock()
+        try:
+            yield
+        finally:
+            dur = ob.clock() - t0
+            ob.stage_seconds.labels(stage=name).observe(dur)
+            if record_span and ctx is not None and ctx.recording:
+                _record(ctx, name, _new_span_id(), ctx.span_id, t0, dur,
+                        attrs)
+
+
+class _Holds:
+    """The holds of one serial resource: the starts of those in
+    progress, and the union of the ended ones as far back as a hold in
+    progress (or a later one) can reach."""
+
+    __slots__ = ("active", "ended")
+
+    def __init__(self):
+        self.active: List[float] = []
+        self.ended: List[Tuple[float, float]] = []     # disjoint, sorted
+
+    def end(self, s: float, e: float) -> float:
+        """End the hold ``[s, e]``; return the part of it that no hold
+        which ended before it covered."""
+        self.active.remove(s)
+        covered = sum(max(0.0, min(b, e) - max(a, s)) for a, b in self.ended)
+        lo, hi = s, e
+        kept = []
+        for a, b in self.ended:
+            if b < lo or a > hi:
+                kept.append((a, b))
+            else:
+                lo, hi = min(lo, a), max(hi, b)
+        kept.append((lo, hi))
+        # A later hold starts no earlier than now, and one in progress
+        # no earlier than its start: nothing before that can overlap.
+        horizon = min(self.active, default=e)
+        self.ended = sorted(iv for iv in kept if iv[1] > horizon)
+        return max(0.0, (e - s) - covered)
+
+
+_HOLDS_LOCK = threading.Lock()
+_HOLDS: Dict[str, _Holds] = {}  # guarded-by: _HOLDS_LOCK
+
+
+@contextlib.contextmanager
+def occupancy(resource: str, **attrs) -> Iterator[None]:
+    """One hold of a shared serial resource (per process, whatever the
+    domain): observe ``<resource>_inflight``, the part of the hold that
+    no hold which ended before it covered, and ``<resource>_queued``,
+    the rest, in the ambient domain's ``warpsim_stage_seconds``. Summed
+    over holds, ``_inflight`` is the length of the union of the holds
+    and ``_inflight + _queued`` their total length. The hold is
+    annotated as ``warpsim.<resource>`` and, when a trace is recording,
+    recorded as a span."""
+    if not enabled():
         yield
-    finally:
-        dur = ob.clock() - t0
-        ob.stage_seconds.labels(stage=name).observe(dur)
-        if ctx is not None and ctx.recording:
-            _record(ctx, name, _new_span_id(), ctx.span_id, t0, dur, attrs)
+        return
+    ctx = _CONTEXT.get()
+    ob = ctx.obs if ctx is not None else default()
+    with _annotation(resource):
+        with _HOLDS_LOCK:
+            holds = _HOLDS.get(resource)
+            if holds is None:
+                holds = _HOLDS[resource] = _Holds()
+            s = ob.clock()
+            holds.active.append(s)
+        try:
+            yield
+        finally:
+            with _HOLDS_LOCK:
+                e = ob.clock()
+                inflight = holds.end(s, e)
+            queued = (e - s) - inflight
+            ob.stage_seconds.labels(stage=resource + "_inflight").observe(
+                inflight)
+            ob.stage_seconds.labels(stage=resource + "_queued").observe(
+                queued)
+            if ctx is not None and ctx.recording:
+                _record(ctx, resource, _new_span_id(), ctx.span_id, s,
+                        e - s, dict(attrs, queued_s=round(queued, 6)))
 
 
 # ---------------------------------------------------------------------------

@@ -1321,7 +1321,10 @@ class SweepRequestHandler(BaseHTTPRequestHandler):
                 return
             svc.bump("requests")
             try:
-                fn()
+                # The handler's time, from here through the written
+                # response; its span in a trace is the server span above.
+                with obs_mod.stage("server" + path, record_span=False):
+                    fn()
             except (KeyError, ValueError) as e:
                 svc.bump("errors")
                 self._try_send({"error": f"{e.__class__.__name__}: {e}"},

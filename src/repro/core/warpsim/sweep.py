@@ -830,8 +830,7 @@ def _run_family_pallas(fam_payloads: List[_GroupPayload],
         cfgs = payload[3]
         groups.append((wl, stream, cfgs))
         pairs.extend((stream, cfg) for cfg in cfgs)
-    with obs_mod.stage("pallas_family", units=len(pairs)):
-        raw = _pallas.run_family(pairs)
+    raw = _pallas.run_family(pairs)
     if raw is None:
         return None, False
     out: List[List[SimResult]] = []

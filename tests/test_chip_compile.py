@@ -75,6 +75,31 @@ def test_warpsim_family_program_compiles_integer_only(one_chip):
     assert "tpu_custom_call" not in hlo       # no kernel on this path
 
 
+def test_family_program_keeps_the_name_the_benchmark_reads():
+    """``engine.warp_events_per_device_s`` finds the family program in
+    the device trace by its module name. Lowered here on the CPU, the
+    program has to carry that name, so that a rename fails this test
+    instead of leaving the metric with nothing to read."""
+    import ast
+
+    reader = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "chipbench", "metrics",
+        "engine.warp_events_per_device_s.py")
+    with open(reader) as f:
+        program = next(
+            ast.literal_eval(node.value) for node in ast.parse(f.read()).body
+            if isinstance(node, ast.Assign)
+            and [t.id for t in node.targets] == ["PROGRAM"])
+    cfg = machines.baseline(32)
+    units = [(_pallas._stream_cols(expand_stream(
+        get_workload("NQU", n_threads=64), cfg)), _pallas._cfg_scalars(cfg))]
+    dims, stacked = _pallas.pack_units(units)
+    with jax.enable_x64(True):
+        text = _pallas._get_launch(*dims).lower(stacked).as_text()
+    assert re.search(r"^module @(\S+)", text, re.M).group(1) == program
+    assert program == "jit__simulate_one"
+
+
 def test_tinyllama_decode_step_compiles_full_width(one_chip):
     """One fused decode step of tinyllama-1.1b at its published width,
     4 serving slots, fits one chip."""

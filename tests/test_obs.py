@@ -381,6 +381,291 @@ def test_kill_switch_makes_hooks_no_ops(monkeypatch):
     assert ob.spans.dump() == []
 
 
+# ---------------------------------------------------------------------------
+# Occupancy of a shared serial resource
+# ---------------------------------------------------------------------------
+
+
+def _holds(clock, resource, plan):
+    """Run holds of `resource` under an injected clock. `plan` is a list
+    of ("enter"|"exit", hold id, time) in clock order; returns the
+    stage histograms' (sum, count) of ``_inflight`` and ``_queued``."""
+    ob = Observability(clock=clock)
+    live = {}
+    with obs_mod.bind(ob):
+        for what, hold, t in plan:
+            clock.t = t
+            if what == "enter":
+                live[hold] = obs_mod.occupancy(resource)
+                live[hold].__enter__()
+            else:
+                live.pop(hold).__exit__(None, None, None)
+    assert not live
+    h = ob.stage_seconds
+    return ((h.labels(stage=resource + "_inflight").sum,
+             h.labels(stage=resource + "_inflight").count),
+            (h.labels(stage=resource + "_queued").sum,
+             h.labels(stage=resource + "_queued").count))
+
+
+def _union(intervals):
+    total, end = 0.0, -math.inf
+    for s, e in sorted(intervals):
+        if e > end:
+            total += e - max(s, end)
+            end = e
+    return total
+
+
+@pytest.mark.parametrize("name,holds", [
+    ("overlapping", {"a": (0.0, 2.0), "b": (1.0, 3.0), "c": (2.5, 5.0)}),
+    ("apart", {"a": (0.0, 1.0), "b": (2.0, 3.0), "c": (4.5, 5.0)}),
+    ("out_of_order", {"a": (0.0, 3.0), "b": (1.0, 2.0)}),
+    ("spans_a_gap", {"a": (0.0, 1.0), "b": (2.0, 3.0), "c": (0.5, 4.0)}),
+])
+def test_occupancy_inflight_is_the_union_of_the_holds(name, holds):
+    events = sorted([(s, "enter", h) for h, (s, _) in holds.items()]
+                    + [(e, "exit", h) for h, (_, e) in holds.items()],
+                    key=lambda ev: (ev[0], ev[1] == "enter"))
+    plan = [(what, h, t) for t, what, h in events]
+    (inflight, n), (queued, nq) = _holds(FakeClock(), "occ_" + name, plan)
+    assert n == nq == len(holds)
+    assert inflight == pytest.approx(_union(holds.values()))
+    assert inflight + queued == pytest.approx(
+        sum(e - s for s, e in holds.values()))
+    assert queued >= 0
+
+
+def test_occupancy_queued_is_time_behind_an_earlier_hold():
+    # b enters while a runs, and waits for it: one second queued.
+    plan = [("enter", "a", 0.0), ("enter", "b", 1.0), ("exit", "a", 2.0),
+            ("exit", "b", 4.0)]
+    (inflight, _), (queued, _) = _holds(FakeClock(), "occ_fifo", plan)
+    assert inflight == pytest.approx(4.0)
+    assert queued == pytest.approx(1.0)
+
+
+def test_occupancy_state_is_per_resource_and_forgets_idle_history():
+    clock = FakeClock()
+    _holds(clock, "occ_r1", [("enter", "a", 0.0), ("exit", "a", 5.0)])
+    # Another resource, overlapping in time, is not queued behind it.
+    (_, _), (queued, _) = _holds(clock, "occ_r2", [("enter", "a", 1.0),
+                                                   ("exit", "a", 2.0)])
+    assert queued == 0.0
+    # Once a resource is idle it keeps no intervals.
+    assert obs_mod._HOLDS["occ_r1"].ended == []
+    assert obs_mod._HOLDS["occ_r1"].active == []
+
+
+def test_occupancy_records_a_span_when_tracing():
+    clock = FakeClock()
+    ob = Observability(clock=clock)
+    with obs_mod.start_trace("study", obs=ob) as ctx:
+        with obs_mod.occupancy("occ_span", units=3):
+            clock.t += 0.5
+    spans = [s for s in ob.spans.dump(ctx.trace_id)
+             if s["name"] == "occ_span"]
+    assert len(spans) == 1 and spans[0]["dur_s"] == 0.5
+    assert spans[0]["attrs"] == {"units": 3, "queued_s": 0.0}
+
+
+# ---------------------------------------------------------------------------
+# Profiler annotations
+# ---------------------------------------------------------------------------
+
+
+class _Annotations:
+    """A stand-in profiler annotation factory that logs what it enters."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+
+        return _Ann()
+
+
+@pytest.fixture
+def annotations():
+    fake = _Annotations()
+    prev = obs_mod.set_annotation_factory(fake)
+    try:
+        yield fake
+    finally:
+        obs_mod.set_annotation_factory(prev)
+
+
+def test_stages_spans_and_holds_enter_warpsim_annotations(annotations):
+    ob = Observability(clock=FakeClock())
+    with obs_mod.start_trace("study", obs=ob):
+        with obs_mod.span("inner"):
+            with obs_mod.stage("trace_build"):
+                pass
+            with obs_mod.occupancy("occ_ann"):
+                pass
+    assert annotations.log == [
+        ("enter", "warpsim.inner"), ("enter", "warpsim.trace_build"),
+        ("exit", "warpsim.trace_build"), ("enter", "warpsim.occ_ann"),
+        ("exit", "warpsim.occ_ann"), ("exit", "warpsim.inner")]
+
+
+def test_stage_annotates_without_a_trace(annotations):
+    with obs_mod.stage("t_obs_ann"):
+        pass
+    with obs_mod.span("untraced"):
+        pass
+    assert [n for _, n in annotations.log] == [
+        "warpsim.t_obs_ann", "warpsim.t_obs_ann",
+        "warpsim.untraced", "warpsim.untraced"]
+
+
+def test_annotation_exits_when_the_stage_raises(annotations):
+    with pytest.raises(RuntimeError):
+        with obs_mod.stage("t_obs_raise"):
+            raise RuntimeError("boom")
+    assert annotations.log[-1] == ("exit", "warpsim.t_obs_raise")
+
+
+def test_no_annotation_without_factory_or_with_obs_off(monkeypatch,
+                                                       annotations):
+    obs_mod.set_annotation_factory(None)
+    with obs_mod.stage("t_obs_none"):
+        pass
+    obs_mod.set_annotation_factory(annotations)
+    monkeypatch.setenv("WARPSIM_OBS", "0")
+    with obs_mod.stage("t_obs_off"):
+        pass
+    with obs_mod.span("t_obs_off"):
+        pass
+    with obs_mod.occupancy("t_obs_off"):
+        pass
+    assert annotations.log == []
+
+
+def test_obs_imports_no_jax():
+    import ast
+    import inspect
+
+    tree = ast.parse(inspect.getsource(obs_mod))
+    mods = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            mods.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            mods.add(node.module or "")
+    assert not {m for m in mods if m.split(".")[0] == "jax"}, mods
+    assert {m.split(".")[0] for m in mods} <= {
+        "__future__", "contextlib", "contextvars", "dataclasses", "hashlib",
+        "math", "re", "threading", "time", "uuid", "collections", "typing",
+        "repro"}
+
+
+def test_compat_installs_the_profiler_annotation(annotations):
+    import jax
+
+    from repro import compat
+
+    compat.annotate_stages()
+    factory = obs_mod.set_annotation_factory(annotations)
+    assert factory is jax.profiler.TraceAnnotation
+
+
+def test_server_stage_times_each_request(tmp_path):
+    svc = SweepService(str(tmp_path), persist_traces=False)
+    with _daemon(svc) as url:
+        ResilientClient([url], sleep=_noop_sleep).healthz()
+    assert svc.obs.stage_seconds.labels(stage="server/healthz").count == 1
+
+
+# ---------------------------------------------------------------------------
+# The device engine's stages, on the CPU
+# ---------------------------------------------------------------------------
+
+LAUNCH_STAGES = ("pallas_pack", "pallas_dispatch", "device_inflight",
+                 "device_queued")
+
+
+def _tiny_cell():
+    from repro.core.warpsim.divergence import expand_stream
+    from repro.core.warpsim.trace import get_workload
+
+    cfg = machines.baseline(32)
+    wl = get_workload("NQU", n_threads=64)
+    return expand_stream(wl, cfg), cfg
+
+
+def _launch(path):
+    from repro.core.warpsim import _pallas
+
+    stream, cfg = _tiny_cell()
+    if path == "run_family":
+        return _pallas.run_family([(stream, cfg)])[0]
+    return _pallas.run_scheduling_loop(
+        stream.n_warps, stream.op_start, stream.issue, stream.kind,
+        stream.blk_off, stream.blk_len, stream.blocks, stream.nbytes, cfg)
+
+
+@pytest.mark.parametrize("path", ["run_scheduling_loop", "run_family"])
+def test_launch_observes_its_four_stages(path):
+    from repro.core.warpsim import _pallas
+
+    if not _pallas.available():
+        pytest.skip(f"no jax: {_pallas.status()}")
+    ob = Observability()
+    with obs_mod.bind(ob):
+        loop = _launch(path)
+    assert loop[0] > 0
+    h = ob.stage_seconds
+    for st in LAUNCH_STAGES:
+        assert h.labels(stage=st).count == 1, st
+    assert h.labels(stage="device_inflight").sum > 0
+    exposition = ob.registry.render()
+    for st in LAUNCH_STAGES:
+        assert f'warpsim_stage_seconds_count{{stage="{st}"}} 1' in exposition
+
+
+def test_launch_stages_land_on_the_profiler_trace(tmp_path):
+    """One launch under the profiler: the host plane of the .xplane.pb
+    holds the pack stage and the device hold by their warpsim names."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro import compat
+    from repro.core.warpsim import _pallas
+
+    if not _pallas.available():
+        pytest.skip(f"no jax: {_pallas.status()}")
+    _launch("run_family")                   # compile outside the trace
+    prev = obs_mod.set_annotation_factory(None)
+    compat.annotate_stages()
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            _launch("run_family")
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        obs_mod.set_annotation_factory(prev)
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = set()
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            host.update(ev.name for line in plane.lines
+                        for ev in line.events)
+    assert {"warpsim.pallas_pack", "warpsim.device",
+            "warpsim.pallas_dispatch"} <= host
+
+
 def test_sampling_is_deterministic_per_trace_id():
     # The decision is a pure function of the trace id and the rate.
     assert obs_mod._sampled("deadbeef") is True          # default rate 1.0
