@@ -166,12 +166,12 @@ def warpsim_phase(study, clock: CompileClock, tmp: str) -> None:
     diff = _differing(served, ref)
     print(f"warpsim served: cells_simulated={served.stats['simulated']} "
           f"trace_families={served.stats['trace_families']} "
-          f"cell_launches={launches} compile_s={ph.compile:.3f} "
+          f"family_launches={launches} compile_s={ph.compile:.3f} "
           f"(summed over daemon threads) wall_s={ph.wall:.3f} "
           f"records_differing_from_native={diff}", flush=True)
     check(served.stats["simulated"] == n_cells, "served run hit a cache")
-    check(launches == n_cells, f"{launches} device launches for "
-          f"{n_cells} served cells")
+    check(launches == served.stats["family_launches"] == n_families,
+          f"{launches} served family launches for {n_families} families")
     check(diff == 0, f"{diff} served records differ from native")
 
 

@@ -25,10 +25,12 @@ Timing engines (``simulate(..., engine=...)`` — all bit-identical):
     native        compiled C scheduling loop (~25x event)
     fast          flat-CSR numpy/heapq loop (always available)
     fast_nested   previous-generation fast path, benchmark baseline
-    pallas        JAX device core; sweeps batch a whole trace
-                  family (all expansion keys x machine variants of
-                  one ThreadTrace) into ONE launch; runs fast only
-                  under WARPSIM_PALLAS=0 (device failures raise)
+    pallas        JAX device core; in-process sweeps and the
+                  daemon's studies batch a whole trace family (all
+                  expansion keys x machine variants of one
+                  ThreadTrace) into ONE launch (GET /cell is a
+                  one-unit launch); runs fast only under
+                  WARPSIM_PALLAS=0 (device failures raise)
     event         reference event loop (the model's ground truth)
     ============= ===================================================
 

@@ -98,8 +98,8 @@ from repro.core.warpsim import mesh as mesh_mod
 from repro.core.warpsim import obs as obs_mod
 from repro.core.warpsim.mesh import MeshConfig
 from repro.core.warpsim.sweep import (
-    MODEL_VERSION, SweepSpec, cell_key, compute_cell, family_major_cells,
-    spec_from_dict, spec_to_dict,
+    MODEL_VERSION, SweepSpec, cell_key, compute_cell, compute_family_pallas,
+    family_major_cells, spec_from_dict, spec_to_dict,
 )
 from repro.core.warpsim.timing import SimResult
 from repro.core.warpsim.trace import BENCHMARKS
@@ -546,6 +546,12 @@ class SweepService:
         """One cell plus how it was served:
         "cache" | "simulated" | "dedup" | "peer".
 
+        The per-cell path: ``GET /cell``, ``GET /peer/cell`` and studies
+        on host engines (a study on the device engine batches the trace
+        family cells this daemon owns instead, :meth:`_family_with_sources`,
+        and sends the cells other mesh members own through here). A
+        device-engine cell here is a one-unit launch.
+
         With a mesh configured, a local miss on a cell this daemon does
         not own first read-throughs to the owner (then the replica
         successors) before simulating; any peer failure degrades to
@@ -556,41 +562,13 @@ class SweepService:
         that converging instead of cycling).
         """
         key = cell_key(bench, cfg, n_threads, seed)
-        with obs_mod.stage("cache_get", key=key[:12]):
-            res = self.cache.get(key)   # optimistic: no service lock held
+        res, fut, owner = self._claim(key)
         if res is not None:
-            self.bump("cells_served")
-            self.bump("cache_hits")
-            self._note_cell(key, "cache")
             return res, "cache"
-        owner = False
-        with self._lock:
-            self.bump("cells_served")
-            fut = self._inflight.get(key)
-            if fut is None:
-                # Re-probe under the lock: the owner of a just-finished
-                # in-flight simulation published to the cache and left the
-                # table between our optimistic probe and here. contains()
-                # first — it skips the hit/miss counters, so the common
-                # cold path doesn't double-count the optimistic miss.
-                res = self.cache.get(key) if self.cache.contains(key) else None
-                if res is not None:
-                    self.bump("cache_hits")
-                    self._note_cell(key, "cache")
-                    return res, "cache"
-                fut = concurrent.futures.Future()
-                self._inflight[key] = fut
-                self._g_inflight.set(len(self._inflight))
-                owner = True
-            else:
-                self.bump("dedup_waits")
         if not owner:
-            res = fut.result()
-            self._note_cell(key, "dedup")
-            return res, "dedup"
+            return self._await(key, fut), "dedup"
         source = "simulated"
         try:
-            res = None
             if not forwarded:
                 res = self._peer_fetch(key, bench, cfg, n_threads, seed)
                 if res is not None:
@@ -601,6 +579,79 @@ class SweepService:
                                    trace_dir=self.trace_dir,
                                    trace_cache=self.session.trace_cache,
                                    expansion_cache=self.session.expansion_cache)
+        except BaseException as e:
+            self._abandon([(key, fut)], e)
+            raise
+        self._publish(key, fut, res, source)
+        return res, source
+
+    def _claim(self, key: str
+               ) -> Tuple[Optional[SimResult],
+                          Optional[concurrent.futures.Future], bool]:
+        """Serve `key` from the cache or claim it in the in-flight table.
+
+        Returns ``(result, None, False)`` on a cache hit, ``(None, future,
+        False)`` while another request simulates the cell (the caller
+        awaits it with :meth:`_await`), and ``(None, future, True)`` when
+        the caller now owns the cell and must end its claim with
+        :meth:`_publish` or :meth:`_abandon`.
+        """
+        with obs_mod.stage("cache_get", key=key[:12]):
+            res = self.cache.get(key)   # optimistic: no service lock held
+        if res is not None:
+            self.bump("cells_served")
+            self.bump("cache_hits")
+            self._note_cell(key, "cache")
+            return res, None, False
+        with self._lock:
+            self.bump("cells_served")
+            fut = self._inflight.get(key)
+            if fut is not None:
+                self.bump("dedup_waits")
+                return None, fut, False
+            # Re-probe under the lock: the owner of a just-finished
+            # in-flight simulation published to the cache and left the
+            # table between our optimistic probe and here. contains()
+            # first — it skips the hit/miss counters, so the common cold
+            # path doesn't double-count the optimistic miss.
+            res = self.cache.get(key) if self.cache.contains(key) else None
+            if res is not None:
+                self.bump("cache_hits")
+                self._note_cell(key, "cache")
+                return res, None, False
+            fut = concurrent.futures.Future()
+            self._inflight[key] = fut
+            self._g_inflight.set(len(self._inflight))
+            return None, fut, True
+
+    def _await(self, key: str, fut: concurrent.futures.Future) -> SimResult:
+        res = fut.result()
+        self._note_cell(key, "dedup")
+        return res
+
+    def _release(self, key: str) -> None:
+        with self._lock:
+            self._inflight.pop(key, None)
+            self._g_inflight.set(len(self._inflight))
+
+    def _abandon(self, owned: Iterable[Tuple[str, concurrent.futures.Future]],
+                 exc: BaseException) -> None:
+        """End the claims in `owned` not yet published: their waiters get
+        `exc`, and the cells leave the in-flight table."""
+        for key, fut in owned:
+            if not fut.done():
+                fut.set_exception(exc)
+                self._release(key)
+
+    def _publish(self, key: str, fut: concurrent.futures.Future,
+                 res: SimResult, source: str) -> None:
+        """End an owned claim with its result ("simulated" or "peer").
+
+        Caches the cell, resolves its future and releases its in-flight
+        slot; a simulated cell is then replicated and passes the
+        ``service.cell`` fault hook, which may raise.
+        """
+        try:
             with obs_mod.stage("cache_put", key=key[:12]):
                 self.cache.put(key, res)
             if source == "simulated":
@@ -610,28 +661,79 @@ class SweepService:
             fut.set_exception(e)
             raise
         finally:
-            with self._lock:
-                self._inflight.pop(key, None)
-                self._g_inflight.set(len(self._inflight))
+            self._release(key)
         self._note_cell(key, source)
-        if source == "simulated":
-            # Mesh durability: push the fresh cell to its replica
-            # successors BEFORE the kill-fault hook below — a daemon
-            # killed right after computing a cell must not take the
-            # fleet's only copy down with its disk.
-            self._replicate_cells([(key, res)])
-            # Chaos hook: "daemon dies after N cells". Checked strictly
-            # AFTER the result is cached, replicated, and the dedup
-            # future resolved — a killed daemon's completed cells stay
-            # reachable (shared root or replicas), which is what makes
-            # failover re-simulate (almost) nothing.
-            fault = self.check_fault(fault_point("service.cell"), marker=key)
-            if fault is not None:
-                if fault.action == "kill":
-                    self.kill()
-                raise FaultError(
-                    f"injected {fault.action} at service.cell ({key[:12]}…)")
-        return res, source
+        if source != "simulated":
+            return
+        # Mesh durability: push the fresh cell to its replica successors
+        # BEFORE the kill-fault hook below — a daemon killed right after
+        # computing a cell must not take the fleet's only copy down with
+        # its disk.
+        self._replicate_cells([(key, res)])
+        # Chaos hook: "daemon dies after N cells". Checked strictly AFTER
+        # the result is cached, replicated, and the dedup future resolved
+        # — a killed daemon's completed cells stay reachable (shared root
+        # or replicas), which is what makes failover re-simulate (almost)
+        # nothing.
+        fault = self.check_fault(fault_point("service.cell"), marker=key)
+        if fault is not None:
+            if fault.action == "kill":
+                self.kill()
+            raise FaultError(
+                f"injected {fault.action} at service.cell ({key[:12]}…)")
+
+    def _family_with_sources(self, group: Sequence[tuple]
+                             ) -> Tuple[List[Tuple[SimResult, str]], bool]:
+        """A study's cells of one trace family on the device engine.
+
+        `group` holds ``(machine name, cfg, bench, n_threads, seed)``
+        cells sharing ``(bench, n_threads, seed)``, in family-major
+        order. The cells this daemon owns (every cell without a mesh) are
+        probed and claimed first; those left to simulate run in one
+        device launch, and each is published as :meth:`cell_with_source`
+        publishes it. Only then does the family wait on anything: cells
+        another request has in flight are awaited, and cells another
+        mesh member owns go through :meth:`cell_with_source` one at a
+        time (a peer fetch each; the owner serves it as a one-unit
+        launch). So no claim is held while waiting on another request or
+        on a peer, and two studies that share a family, on one daemon or
+        across a mesh, never wait on each other. Returns the ``(result,
+        source)`` pairs in `group` order and whether a launch ran.
+        """
+        out: List[Optional[Tuple[SimResult, str]]] = [None] * len(group)
+        owned, waiting, remote = [], [], []
+        for i, (_mname, cfg, bench, n_threads, seed) in enumerate(group):
+            key = cell_key(bench, cfg, n_threads, seed)
+            if self.mesh is not None and self.mesh.fetch_order(key):
+                remote.append(i)
+                continue
+            res, fut, owner = self._claim(key)
+            if res is not None:
+                out[i] = (res, "cache")
+            else:
+                (owned if owner else waiting).append((i, key, fut))
+        launched = False
+        if owned:
+            _mname, _cfg, bench, n_threads, seed = group[owned[0][0]]
+            try:
+                results, launched = compute_family_pallas(
+                    bench, n_threads, seed, [group[i][1] for i, _, _ in owned],
+                    trace_dir=self.trace_dir,
+                    trace_cache=self.session.trace_cache,
+                    expansion_cache=self.session.expansion_cache)
+                for (i, key, fut), res in zip(owned, results):
+                    self._publish(key, fut, res, "simulated")
+                    out[i] = (res, "simulated")
+            except BaseException as e:
+                self._abandon([(key, fut) for _, key, fut in owned], e)
+                raise
+        for i, key, fut in waiting:
+            out[i] = (self._await(key, fut), "dedup")
+        for i in remote:
+            _mname, cfg, bench, n_threads, seed = group[i]
+            out[i] = self.cell_with_source(bench, cfg, n_threads, seed,
+                                           engine="pallas")
+        return out, launched
 
     # -------------------------------------------------------------- mesh
 
@@ -869,17 +971,25 @@ class SweepService:
         """Serve a whole :class:`~repro.core.warpsim.api.Study`.
 
         The facade core of the daemon (``POST /study``; the legacy
-        ``POST /sweep`` shape is a shim over it). Cells run through
-        :meth:`cell_with_source` in family-major order, so uncached runs
-        get the sweep engine's trace/expansion sharing through the
-        session-owned LRUs, and every cell dedups against concurrent
-        ``/cell`` and ``/sweep``/``/study`` requests. Trace families are
-        fanned across a small thread pool (one family per task keeps its
-        cells' trace/stream locality) so a cold grid uses the host's
-        cores — the native engine releases the GIL inside its C call, and
-        the cache stack is lock-guarded, so threads are both safe and
-        effective here. The result's `stats` mirrors
-        ``run_sweep_with_stats``'s snapshot keys (plus ``dedup_waits``).
+        ``POST /sweep`` shape is a shim over it). Cells run in
+        family-major order, so uncached runs get the sweep engine's
+        trace/expansion sharing through the session-owned LRUs, and every
+        cell dedups against concurrent ``/cell`` and ``/sweep``/``/study``
+        requests. On the host engines each cell runs through
+        :meth:`cell_with_source` in turn (compute, publish, fault hook,
+        next cell). When the engine resolves to ``pallas`` and the device
+        engine is available, the uncached cells of a trace family that
+        this daemon owns are simulated in one device launch
+        (:meth:`_family_with_sources`), as the in-process sweep does; on
+        a mesh, the cells other members own are fetched one at a time and
+        stay one-unit launches at their owners. Trace families are fanned
+        across a small thread pool (one family per task keeps its cells'
+        trace/stream locality) so a cold grid uses the host's cores — the
+        native engine releases the GIL inside its C call, and the cache
+        stack is lock-guarded, so threads are both safe and effective
+        here. The result's `stats` mirrors ``run_sweep_with_stats``'s
+        snapshot keys (plus ``dedup_waits``); ``family_launches`` counts
+        this study's device launches.
         """
         t0 = time.time()
         engine = (None if study.engine in (None, "auto", "")
@@ -909,22 +1019,24 @@ class SweepService:
         ctx = obs_mod.current()
 
         def run_family(group):
-            out = []
             with obs_mod.activate(ctx):
-                for mname, cfg, bench, n_threads, seed in group:
-                    out.append(((mname, cfg, bench, n_threads, seed),
-                                self.cell_with_source(bench, cfg, n_threads,
-                                                      seed, engine=engine)))
-            return out
+                if ((engine or self.engine) == "pallas"
+                        and _pallas.available()):
+                    served, launched = self._family_with_sources(group)
+                    return list(zip(group, served)), int(launched)
+                return [(cell, self.cell_with_source(
+                    cell[2], cell[1], cell[3], cell[4], engine=engine))
+                    for cell in group], 0
 
         workers = min(8, os.cpu_count() or 1, len(families)) or 1
         if workers > 1:
             with concurrent.futures.ThreadPoolExecutor(workers) as pool:
-                per_family = pool.map(run_family,
-                                      (g for _, g in families))
-                done = [cell for fam in per_family for cell in fam]
+                per_family = list(pool.map(run_family,
+                                           (g for _, g in families)))
         else:
-            done = [cell for _, g in families for cell in run_family(g)]
+            per_family = [run_family(g) for _, g in families]
+        done = [cell for fam, _ in per_family for cell in fam]
+        family_launches = sum(n for _, n in per_family)
 
         for (mname, cfg, bench, n_threads, seed), (res, src) in done:
             counts[src] += 1
@@ -947,6 +1059,7 @@ class SweepService:
             expansions_saved=uncached - len(sim_groups),
             trace_families=len(sim_families),
             traces_shared=len(sim_groups) - len(sim_families),
+            family_launches=family_launches,
             expansion_cache_hits=ecache.hits - exp0[0],
             expansion_cache_misses=ecache.misses - exp0[1],
             trace_cache_hits=tcache.hits - trc0[0],

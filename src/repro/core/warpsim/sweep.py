@@ -73,13 +73,14 @@ import concurrent.futures
 import dataclasses
 import functools
 import hashlib
+import itertools
 import json
 import os
 import sys
 import tempfile
 import threading
 import warnings
-from typing import Dict, List, Mapping, Optional, Tuple
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -841,6 +842,35 @@ def _run_family_pallas(fam_payloads: List[_GroupPayload],
                     for j, cfg in enumerate(cfgs)])
         i += len(cfgs)
     return out, True
+
+
+def compute_family_pallas(bench: str, n_threads: Optional[int], seed: int,
+                          cfgs: Sequence[MachineConfig],
+                          trace_dir: Optional[str],
+                          trace_cache: TraceCache,
+                          expansion_cache: ExpansionCache
+                          ) -> Tuple[List[SimResult], bool]:
+    """Simulate machine variants of one trace family in one device launch.
+
+    The daemon's batched sibling of :func:`compute_cell`: each run of
+    consecutive `cfgs` that share an expansion key becomes one payload of
+    :func:`_run_family_pallas` (callers pass cells in
+    :func:`family_major_cells` order, so each key is one run), with
+    streams from the given LRUs. Returns one result per config, in `cfgs`
+    order, and whether a launch ran; with ``WARPSIM_PALLAS`` off each
+    payload runs through :func:`_run_group` instead.
+    """
+    payloads: List[_GroupPayload] = [
+        (bench, n_threads, seed, list(members), "pallas", True, True,
+         trace_dir)
+        for _key, members in itertools.groupby(cfgs, key=expansion_key)]
+    fam_res, launched = _run_family_pallas(payloads, trace_cache,
+                                           expansion_cache)
+    if not launched:
+        fam_res = [_run_group(payload, trace_cache=trace_cache,
+                              expansion_cache=expansion_cache)
+                   for payload in payloads]
+    return [res for group_res in fam_res for res in group_res], launched
 
 
 def compute_cell(bench: str, cfg: MachineConfig,

@@ -609,9 +609,11 @@ def _simulate_pallas(name: str, warp_ops: Ops, cfg: MachineConfig
                      ) -> SimResult:
     """Single-cell dispatch onto the device family core.
 
-    One cell is a one-unit family launch. The real win — one launch for a
-    whole trace family — is driven by ``sweep.run_sweep_with_stats``,
-    which batches every (expansion key x machine variant) of a workload
+    One cell is a one-unit family launch (the daemon's ``GET /cell``).
+    The real win — one launch for a whole trace family — is driven by
+    ``sweep.run_sweep_with_stats`` in-process and by the daemon's
+    ``SweepService.study`` (through ``sweep.compute_family_pallas``),
+    which batch every (expansion key x machine variant) of a workload
     into a single ``_pallas.run_family`` call. Runs ``fast`` only when
     ``WARPSIM_PALLAS=0``; a device failure raises.
     """

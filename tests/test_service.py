@@ -684,3 +684,436 @@ def test_healthz_pallas_kill_switch_flips_on_live_daemon(
 
     monkeypatch.delenv("WARPSIM_PALLAS")
     assert svc.healthz()["engine"] == "pallas"
+
+
+# ------------------------------------- device engine: one launch a family
+
+pallas_required = pytest.mark.skipif(
+    not __import__("repro.core.warpsim._pallas",
+                   fromlist=["_pallas"]).available(),
+    reason="jax not importable (or WARPSIM_PALLAS=0)")
+
+
+def _family_study(engine="pallas", seed=0):
+    """One benchmark x the paper suite at one seed: one trace family."""
+    from repro.core.warpsim import api
+    return api.Study(benches=("BFS",), machines=machines.paper_suite(),
+                     n_threads=64, seeds=(seed,), engine=engine)
+
+
+def _records(res):
+    return [(r.machine, r.bench, r.seed, dataclasses.asdict(r.result))
+            for r in res.records]
+
+
+def _native_records():
+    from repro.core.warpsim import api
+    return _records(api.Session().run(_family_study("native")))
+
+
+@pytest.fixture()
+def pallas_live(tmp_path):
+    """A device-engine SweepService bound to an ephemeral HTTP port."""
+    svc = SweepService(str(tmp_path / "cache"), engine="pallas",
+                       persist_traces=False)
+    httpd = serve(svc)
+    thread = threading.Thread(target=httpd.serve_forever, daemon=True)
+    thread.start()
+    url = "http://%s:%d" % httpd.server_address[:2]
+    try:
+        yield svc, url
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+
+
+@pallas_required
+def test_served_pallas_study_is_one_family_launch(pallas_live):
+    """A served 1-benchmark x paper-suite study is one trace family, so
+    one device launch, with records bit-identical to the native engine
+    and to the in-process pallas study."""
+    from repro.core.warpsim import _pallas, api
+
+    svc, url = pallas_live
+    before = _pallas.launch_count()
+    served = api.Session(backend=api.ServiceBackend(url=url)).run(
+        _family_study())
+    assert _pallas.launch_count() - before == 1
+    assert served.stats["family_launches"] == 1
+    assert served.stats["simulated"] == 6 == svc.counters["simulated"]
+    inproc = api.Session().run(_family_study())
+    assert inproc.stats["family_launches"] == 1
+    assert _records(served) == _native_records() == _records(inproc)
+
+
+@pallas_required
+def test_served_pallas_study_again_is_all_cache_hits(pallas_live):
+    from repro.core.warpsim import _pallas, api
+
+    _svc, url = pallas_live
+    session = api.Session(backend=api.ServiceBackend(url=url))
+    first = session.run(_family_study())
+    before = _pallas.launch_count()
+    again = session.run(_family_study())
+    assert _pallas.launch_count() == before
+    assert again.stats["family_launches"] == 0
+    assert again.stats["cache_hits"] == 6 and again.stats["simulated"] == 0
+    assert _records(again) == _records(first)
+
+
+@pallas_required
+def test_cell_read_during_family_launch_is_deduplicated(pallas_live,
+                                                        monkeypatch):
+    """A GET /cell for a cell the study's launch is simulating parks on
+    the study's future: one simulation of that cell in total."""
+    from repro.core.warpsim import _pallas, api
+
+    svc, url = pallas_live
+    entered, release = threading.Event(), threading.Event()
+    real = service_mod.compute_family_pallas
+
+    def held(*args, **kwargs):
+        entered.set()
+        assert release.wait(30)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(service_mod, "compute_family_pallas", held)
+    before = _pallas.launch_count()
+    out = {}
+    study = threading.Thread(target=lambda: out.update(study=api.Session(
+        backend=api.ServiceBackend(url=url)).run(_family_study())))
+    study.start()
+    assert entered.wait(30)
+    read = threading.Thread(target=lambda: out.update(
+        cell=SweepClient(url).cell("BFS", "SW+", n_threads=64)))
+    read.start()
+    assert _wait(lambda: svc.counters["dedup_waits"] == 1)
+    release.set()
+    study.join(60)
+    read.join(60)
+    assert not study.is_alive() and not read.is_alive()
+
+    assert _pallas.launch_count() - before == 1
+    assert svc.counters["simulated"] == 6
+    assert out["study"].stats["family_launches"] == 1
+    sw = [r for r in out["study"].records if r.machine == "SW+"]
+    assert dataclasses.asdict(out["cell"]) == dataclasses.asdict(sw[0].result)
+    assert _records(out["study"]) == _native_records()
+
+
+@pallas_required
+def test_cell_in_flight_is_awaited_not_launched_again(tmp_path, monkeypatch):
+    """A cell another request is already simulating stays out of the
+    study's launch; the study awaits its future."""
+    from repro.core.warpsim import _pallas
+
+    svc = SweepService(str(tmp_path), engine="pallas", persist_traces=False)
+    entered, release = threading.Event(), threading.Event()
+    real_cell = service_mod.compute_cell
+
+    def held_cell(*args, **kwargs):
+        entered.set()
+        assert release.wait(30)
+        return real_cell(*args, **kwargs)
+
+    launched = []
+    real_family = service_mod.compute_family_pallas
+
+    def recorded(bench, n_threads, seed, cfgs, **kwargs):
+        launched.append([cfg.name for cfg in cfgs])
+        return real_family(bench, n_threads, seed, cfgs, **kwargs)
+
+    monkeypatch.setattr(service_mod, "compute_cell", held_cell)
+    monkeypatch.setattr(service_mod, "compute_family_pallas", recorded)
+    before = _pallas.launch_count()
+    out = {}
+    cell = threading.Thread(target=lambda: out.update(
+        cell=svc.cell_with_source("BFS", machines.sw_plus(), 64, 0)))
+    cell.start()
+    assert entered.wait(30)
+    study = threading.Thread(
+        target=lambda: out.update(study=svc.study(_family_study())))
+    study.start()
+    assert _wait(lambda: launched and svc.counters["dedup_waits"] == 1, 60)
+    release.set()
+    cell.join(60)
+    study.join(60)
+    assert not cell.is_alive() and not study.is_alive()
+
+    assert len(launched) == 1 and len(launched[0]) == 5
+    assert machines.sw_plus().name not in launched[0]
+    assert out["cell"][1] == "simulated"
+    stats = out["study"].stats
+    assert stats["dedup_waits"] == 1 and stats["simulated"] == 5
+    assert stats["family_launches"] == 1
+    assert _pallas.launch_count() - before == 2     # one cell, one family
+    assert svc.counters["simulated"] == 6
+    assert _records(out["study"]) == _native_records()
+
+
+@pallas_required
+@pytest.mark.parametrize("switch_read", ["before the family",
+                                         "at the launch"])
+def test_pallas_kill_switch_serves_cells_one_at_a_time(
+        tmp_path, monkeypatch, switch_read):
+    """WARPSIM_PALLAS=0 makes a served pallas study run cell by cell on
+    the flat engines, whether the daemon sees the switch before choosing
+    the family path or only when the family launches."""
+    from repro.core.warpsim import _pallas
+
+    svc = SweepService(str(tmp_path), engine="pallas", persist_traces=False)
+    monkeypatch.setenv("WARPSIM_PALLAS", "0")
+    monkeypatch.setattr(_pallas, "_warned", False)
+    if switch_read == "at the launch":
+        monkeypatch.setattr(_pallas, "available", lambda: True)
+    before = _pallas.launch_count()
+    with pytest.warns(RuntimeWarning, match="pallas"):
+        res = svc.study(_family_study())
+    assert _pallas.launch_count() == before
+    assert res.stats["family_launches"] == 0
+    assert res.stats["simulated"] == 6 and not svc._inflight
+    monkeypatch.delenv("WARPSIM_PALLAS")
+    assert _records(res) == _native_records()
+
+
+@pallas_required
+@pytest.mark.parametrize("after", [2, 100])
+def test_service_cell_fault_fires_per_cell_after_it_is_cached(
+        tmp_path, after):
+    """The service.cell hook runs once per simulated cell of a family
+    launch, each time after that cell is cached; a kill mid-family ends
+    the rest of the family's claims with the fault."""
+    from repro.core.warpsim.faults import FaultError, FaultPlan
+
+    svc = SweepService(
+        str(tmp_path), engine="pallas", persist_traces=False,
+        fault_plan=FaultPlan.from_spec(f"service.cell:kill,after={after}"))
+    checked = []
+    real = svc.check_fault
+
+    def check(point, marker=None):
+        if point == "service.cell":
+            checked.append(svc.cache.contains(marker))
+        return real(point, marker)
+
+    svc.check_fault = check
+    study = _family_study()
+    keys = [cell_key(b, cfg, n, s)
+            for _m, cfg, b, n, s in family_major_cells(study.to_spec().cells())]
+    if after < len(keys):
+        with pytest.raises(FaultError):
+            svc.study(study)
+        assert svc.dead
+        assert checked == [True] * (after + 1)
+        assert [svc.cache.contains(k) for k in keys] == (
+            [True] * (after + 1) + [False] * (len(keys) - after - 1))
+    else:
+        res = svc.study(study)
+        assert checked == [True] * len(keys) and not svc.dead
+        assert res.stats["family_launches"] == 1
+    assert not svc._inflight
+
+
+def _family_on_host(launched):
+    """Stand-in for ``compute_family_pallas``: the claim/publish
+    bookkeeping under test, with each cell on the native engine (no
+    device compiles); appends every simulated cell key to `launched`
+    and reports a launch."""
+    lock = threading.Lock()
+
+    def run(bench, n_threads, seed, cfgs, **kwargs):
+        out = [sweep_mod.compute_cell(bench, cfg, n_threads=n_threads,
+                                      seed=seed, engine="native")
+               for cfg in cfgs]
+        with lock:
+            launched.extend(cell_key(bench, cfg, n_threads, seed)
+                            for cfg in cfgs)
+        return out, True
+
+    return run
+
+
+@pallas_required
+def test_studies_owning_parts_of_each_others_family_both_finish(
+        tmp_path, monkeypatch):
+    """Study A owns ws8 and finds ws16 in flight; study B owns ws16 and
+    finds ws8 in flight. Each launches what it owns before it waits, so
+    neither waits on the other for ever."""
+    from repro.core.warpsim import api
+
+    svc = SweepService(str(tmp_path), engine="pallas", persist_traces=False)
+    launched = []
+    monkeypatch.setattr(service_mod, "compute_family_pallas",
+                        _family_on_host(launched))
+    a_first, b_both = threading.Event(), threading.Event()
+    b_keys = []
+    real_claim = svc._claim
+
+    def claim(key):
+        got = real_claim(key)
+        name = threading.current_thread().name
+        if name == "study-a" and not a_first.is_set():
+            a_first.set()
+            assert b_both.wait(30)
+        elif name == "study-b":
+            b_keys.append(key)
+            if len(b_keys) == 2:
+                b_both.set()
+        return got
+
+    svc._claim = claim
+    suite = machines.paper_suite()
+    out = {}
+
+    def run(tag, order):
+        out[tag] = svc.study(api.Study(
+            benches=("BFS",), machines={m: suite[m] for m in order},
+            n_threads=64, engine="pallas"))
+
+    a = threading.Thread(target=run, args=("a", ("ws8", "ws16")),
+                         name="study-a", daemon=True)
+    b = threading.Thread(target=run, args=("b", ("ws16", "ws8")),
+                         name="study-b", daemon=True)
+    a.start()
+    assert a_first.wait(30)
+    b.start()
+    a.join(30)
+    b.join(30)
+    assert not a.is_alive() and not b.is_alive()
+    assert sorted(launched) == sorted(set(launched)) and len(launched) == 2
+    assert out["a"].stats["dedup_waits"] + out["b"].stats["dedup_waits"] >= 1
+    assert not svc._inflight
+
+
+@pallas_required
+def test_overlapping_family_studies_and_reads_simulate_each_cell_once(
+        tmp_path, monkeypatch):
+    """Stress: more threads than cores run studies over overlapping
+    machine subsets of the same families, and single-cell reads, on one
+    daemon. Every cell is simulated exactly once, every answer is the
+    native engine's, and no claim is left in flight."""
+    import concurrent.futures
+    import sys
+
+    from repro.core.warpsim import api
+
+    svc = SweepService(str(tmp_path), engine="pallas", persist_traces=False)
+    launched = []
+    monkeypatch.setattr(service_mod, "compute_family_pallas",
+                        _family_on_host(launched))
+    suite = machines.paper_suite()
+    names = list(suite)
+    want = {(m, s): sweep_mod.compute_cell("BFS", suite[m], n_threads=64,
+                                           seed=s, engine="native")
+            for m in names for s in (0, 1)}
+
+    def job(k):
+        seed = k % 2
+        if k % 3 == 2:
+            m = names[k % len(names)]
+            res, _src = svc.cell_with_source("BFS", suite[m], 64, seed,
+                                             engine="native")
+            return [(m, seed, res)]
+        subset = [names[(k + j) % len(names)] for j in range(3)]
+        res = svc.study(api.Study(
+            benches=("BFS",), machines={m: suite[m] for m in subset},
+            n_threads=64, seeds=(seed,), engine="pallas"))
+        return [(r.machine, r.seed, r.result) for r in res.records]
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with concurrent.futures.ThreadPoolExecutor(
+                4 * (os.cpu_count() or 1)) as pool:
+            answers = [a for f in [pool.submit(job, k) for k in range(48)]
+                       for a in f.result(timeout=120)]
+    finally:
+        sys.setswitchinterval(old)
+
+    assert len(launched) == len(set(launched))
+    assert svc.counters["simulated"] == len({(m, s) for m, s, _ in answers})
+    assert not svc._inflight
+    for m, seed, res in answers:
+        assert dataclasses.asdict(res) == dataclasses.asdict(want[(m, seed)])
+
+
+@pallas_required
+def test_mesh_daemons_serving_one_family_never_wait_on_each_other(
+        tmp_path, monkeypatch):
+    """Two meshed device-engine daemons serve the same family at once,
+    each owning part of it. Both reach their first peer fetch before
+    either goes on; each has launched and published what it owns by
+    then, so the owner answers the other's fetch from its cache. Every
+    cell is simulated once fleet-wide, and no fetch waits out the peer
+    timeout and falls back to simulating locally."""
+    from repro.core.warpsim import api
+    from repro.core.warpsim.mesh import MeshConfig
+
+    launched = []
+    monkeypatch.setattr(service_mod, "compute_family_pallas",
+                        _family_on_host(launched))
+    svcs = [SweepService(str(tmp_path / f"root{i}"), engine="pallas",
+                         persist_traces=False, mesh=False)
+            for i in range(2)]
+    servers = [serve(svc) for svc in svcs]
+    for httpd in servers:
+        threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    urls = ["http://%s:%d" % httpd.server_address[:2] for httpd in servers]
+    peer_timeout = 10.0
+    for svc, url in zip(svcs, urls):
+        svc.configure_mesh(MeshConfig.build(url, urls, replication=1,
+                                            peer_timeout=peer_timeout))
+    suite = machines.paper_suite()
+
+    def owned_by(svc, seed):
+        return [m for m, cfg in suite.items()
+                if not svc.mesh.fetch_order(cell_key("BFS", cfg, 64, seed))]
+
+    # Ownership follows the ephemeral ports: take a family both own part of.
+    seed = next(s for s in range(64)
+                if all(0 < len(owned_by(svc, s)) < len(suite)
+                       for svc in svcs))
+    both_fetch = threading.Barrier(2, timeout=30)
+    for svc in svcs:
+        real_fetch = svc._peer_fetch
+        fetched = set()
+
+        def fetch(*args, _real=real_fetch, _fetched=fetched):
+            name = threading.current_thread().name
+            if name.startswith("study-") and name not in _fetched:
+                _fetched.add(name)
+                both_fetch.wait()
+            return _real(*args)
+
+        svc._peer_fetch = fetch
+    out = {}
+
+    def run(i):
+        out[i] = svcs[i].study(_family_study(seed=seed))
+
+    threads = [threading.Thread(target=run, args=(i,), name=f"study-{i}",
+                                daemon=True) for i in range(2)]
+    t0 = time.monotonic()
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(3 * peer_timeout)
+        elapsed = time.monotonic() - t0
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        for httpd in servers:
+            httpd.shutdown()
+            httpd.server_close()
+
+    assert elapsed < peer_timeout
+    assert sorted(launched) == sorted(set(launched)) and len(launched) == 6
+    assert sum(svc.counters["simulated"] for svc in svcs) == 6
+    assert [svc.counters["peer_fallbacks"] for svc in svcs] == [0, 0]
+    for i, svc in enumerate(svcs):
+        stats = out[i].stats
+        assert stats["family_launches"] == 1
+        assert stats["simulated"] == len(owned_by(svc, seed))
+        assert stats["peer_hits"] == len(suite) - stats["simulated"]
+        assert not svc._inflight
+    assert _records(out[0]) == _records(out[1])
