@@ -30,10 +30,18 @@ thread-local ``jax.enable_x64(True)``.
 The same decision sequence as the reference event loop is replayed —
 argmin pop order, LRU eviction by unique touch tick, pending-line fill
 minimum, SW+ merge window. The SW+ outstanding table becomes a dense
-``[n_sms, n_unique_blocks]`` array (exact: the dict's >4096-entry prune only
-drops entries that can never merge again, so *any* exact map is
-equivalent). The golden + hypothesis tests in ``tests/test_golden.py``
-assert ``pallas == native == fast == event`` on every field.
+``[n_sms, n_slots]`` array, a block's slot numbering it among the distinct
+blocks its own SM's warps touch (exact: a merge only ever matches within
+one SM, and the dict's >4096-entry prune only drops entries that can never
+merge again, so *any* exact map is equivalent). The L1 tags hold the same
+slots. The L1 and SW+ tables are flat vectors, so that the compiler keeps
+one layout for them through every gather and scatter. The units of a
+launch step in lockstep: each while-loop step pops one op in every unit
+not yet done, and one inner loop serves that step's block lookups and
+store writes, as many trips as the step's largest op in any unit; a unit
+with less to do masks its writes. The golden + hypothesis tests in
+``tests/test_golden.py`` assert ``pallas == native == fast == event`` on
+every field.
 
 Gating: ``WARPSIM_PALLAS=0`` (re-read on every call, so a live daemon can
 be disabled without restart) makes :func:`available` return False and the
@@ -45,10 +53,17 @@ engine exists for accelerator-resident grids and must be asked for.
 
 :data:`LAUNCHES` counts completed family launches; the sweep layer and the
 bench-smoke CI assert on it (a family must cost one launch, not N cells).
+Each launch also counts its loop steps (the outer loop's steps plus the
+inner loop's trips, as the device ran them) and the bytes of one unit's
+loop-carried state in its bucket: the recent launches' in the module
+(:func:`loop_steps`, :func:`state_bytes`), every launch's in the ambient
+obs domain's ``warpsim_pallas_loop_steps_total`` counter and
+``warpsim_pallas_state_bytes`` gauge (the largest seen).
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import threading
 import warnings
@@ -64,8 +79,12 @@ from repro.core.warpsim import obs as obs_mod
 # threads launch concurrently, so increments take the lock.
 LAUNCHES = 0
 _LAUNCH_LOCK = threading.Lock()
+# (loop steps, state bytes) of the most recent launches, newest last.
+_RECENT = collections.deque(maxlen=4096)  # guarded-by: _LAUNCH_LOCK
 
 _modules_cache = None       # (jax, jnp, lax) once imported
+# The name of the vmapped axis over a launch's units.
+_UNITS = "units"
 _import_attempted = False
 _import_error: Optional[str] = None
 _probe_result: Optional[bool] = None
@@ -132,6 +151,30 @@ def available() -> bool:
 
 def launch_count() -> int:
     return LAUNCHES
+
+
+def _recent(last: int) -> Optional[List[Tuple[int, int]]]:
+    """The `last` launches' (loop steps, state bytes); None when fewer
+    are remembered."""
+    with _LAUNCH_LOCK:
+        if last > len(_RECENT):
+            return None
+        return list(_RECENT)[len(_RECENT) - last:]
+
+
+def loop_steps(last: int) -> Optional[int]:
+    """Loop steps of the `last` launches (None when fewer are
+    remembered): each launch's outer steps plus inner trips, counted on
+    the device, which set how long it lasts."""
+    recent = _recent(last)
+    return None if recent is None else sum(n for n, _ in recent)
+
+
+def state_bytes(last: int) -> Optional[int]:
+    """Bytes of one unit's loop-carried state in a launch's bucket, the
+    largest of the `last` launches (None when fewer are remembered)."""
+    recent = _recent(last)
+    return None if recent is None else max((b for _, b in recent), default=0)
 
 
 def status(probe: bool = False) -> dict:
@@ -270,145 +313,138 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
             next0,
             jnp.zeros((n_sms_pad,), i64),                       # issue_free
             jnp.zeros((nctrl_pad,), i64),                       # ctrl_free
-            jnp.full((n_sms_pad, n_sets_pad, ways_pad), -1, i64),   # tags
-            jnp.zeros((n_sms_pad, n_sets_pad, ways_pad), i64),      # ticks
-            jnp.zeros((n_sms_pad, n_sets_pad, ways_pad), i64),      # fills
+            # L1 tags, ticks and fills, flat over [sm, set, way].
+            jnp.full((n_sms_pad * n_sets_pad * ways_pad,), -1, i64),
+            jnp.zeros((n_sms_pad * n_sets_pad * ways_pad,), i64),
+            jnp.zeros((n_sms_pad * n_sets_pad * ways_pad,), i64),
             jnp.zeros((n_sms_pad,), i64),                       # tick ctr
-            # Outstanding SW+ completions; -1 sorts below every time.
-            jnp.full((n_sms_pad, n_slots_pad), -1, i64),
+            # Outstanding SW+ completions, flat over [sm, slot]; -1 sorts
+            # below every time.
+            jnp.full((n_sms_pad * n_slots_pad,), -1, i64),
             jnp.zeros((), i64),                                 # offchip
             jnp.zeros((), i64),                                 # merged
             jnp.zeros((), i64),                                 # l1 hits
+            jnp.zeros((), i64),                 # outer steps + inner trips
         )
 
+        # The loops' trip counts are the largest over the launch's units
+        # (`pmax` over the vmapped axis), so that vmap keeps them scalar:
+        # a per-unit trip count would make it select the whole carried
+        # state, L1 and SW+ tables included, after every step. A unit
+        # with nothing to do in a step masks its writes instead.
         def cond(st):
-            return jnp.any(st[0] < INF)
+            return lax.pmax(jnp.any(st[0] < INF).astype(jnp.int32),
+                            _UNITS) > 0
 
         def body(st):
             (ready, next_idx, issue_free, ctrl_free, tags, ticks, fills,
-             tickc, outst, off_n, mrg_n, hit_n) = st
+             tickc, outst, off_n, mrg_n, hit_n, steps) = st
             # Heap pop: first minimum == lowest warp id on ready-time ties,
             # exactly heapq's (time, warp) lexicographic order.
             w = jnp.argmin(ready)
             ready_t = ready[w]
+            active = ready_t < INF          # false once this unit is done
             sm = sm_of[w]
             i = next_idx[w]
             t_start = jnp.maximum(ready_t, issue_free[sm])
             t_acc = add(t_start, issue_col[i])
-            issue_free = issue_free.at[sm].set(t_acc)
+            issue_free = issue_free.at[sm].set(
+                jnp.where(active, t_acc, issue_free[sm]))
             o = off_col[i]
             n_blk = len_col[i]
+            is_load = active & (kind_col[i] == 1)
+            is_store = active & (kind_col[i] == 2)
 
-            op_state = (ctrl_free, tags, ticks, fills, tickc, outst,
-                        off_n, mrg_n, hit_n)
-
-            def compute_op(s):
-                return (add(t_acc, depth),) + s
-
-            def load_op(s):
-                (ctrl_free, tags, ticks, fills, tickc, outst,
-                 off_n, mrg_n, hit_n) = s
-
-                def blk(j, c):
-                    (done, ctrl_free, tags, ticks, fills, tick, outst,
-                     off_n, mrg_n, hit_n) = c
-                    bi = o + j
-                    b_slot = slot_col[bi]
-                    b_ctrl = ctrl_col[bi]
-                    b_si = si_col[bi]
-                    # L1 lookup (pending lines visible with fill time);
-                    # every lookup is one LRU touch tick.
-                    tick = tick + 1
-                    row = tags[sm, b_si]
-                    match = (row == b_slot) & way_mask
-                    present = jnp.any(match)
-                    widx = jnp.argmax(match)
-                    fill = fills[sm, b_si, widx]
-                    ticks = ticks.at[sm, b_si, widx].set(
-                        jnp.where(present, tick, ticks[sm, b_si, widx]))
-                    is_hit = present & (fill <= t_acc)
-                    out = outst[sm, b_slot]
-                    is_merge = (~is_hit) & ideal & (out > t_acc)
-                    do_dram = (~is_hit) & (~is_merge)
-                    # DRAM request (full 64 B read transaction).
-                    cf = ctrl_free[b_ctrl]
-                    start = jnp.maximum(cf, t_acc)
-                    completion = add(add(start, dram_lat), svc_unit)
-                    ctrl_free = ctrl_free.at[b_ctrl].set(
-                        jnp.where(do_dram, add(start, svc_unit), cf))
-                    # L1 fill / pending-line allocation.
-                    tick = tick + do_dram.astype(i64)
-                    valid = (row != -1) & way_mask
-                    empties = (~valid) & way_mask
-                    has_empty = jnp.any(empties)
-                    tick_row = ticks[sm, b_si]
-                    victim = jnp.argmin(
-                        jnp.where(valid, tick_row, tick_inf))  # LRU
-                    ins_way = jnp.where(has_empty, jnp.argmax(empties),
-                                        victim)
-                    upd_way = jnp.where(present, widx, ins_way)
-                    tags = tags.at[sm, b_si, ins_way].set(
-                        jnp.where(do_dram & (~present), b_slot,
-                                  tags[sm, b_si, ins_way]))
-                    ticks = ticks.at[sm, b_si, upd_way].set(
-                        jnp.where(do_dram, tick,
-                                  ticks[sm, b_si, upd_way]))
-                    new_fill = jnp.where(
-                        present, jnp.minimum(fill, completion), completion)
-                    fills = fills.at[sm, b_si, upd_way].set(
-                        jnp.where(do_dram, new_fill,
-                                  fills[sm, b_si, upd_way]))
-                    outst = outst.at[sm, b_slot].set(
-                        jnp.where(do_dram & ideal, completion, out))
-                    off_n = off_n + do_dram.astype(i64)
-                    mrg_n = mrg_n + is_merge.astype(i64)
-                    hit_n = hit_n + is_hit.astype(i64)
-                    done = jnp.where(is_merge, jnp.maximum(done, out), done)
-                    done = jnp.where(do_dram,
-                                     jnp.maximum(done, completion), done)
-                    return (done, ctrl_free, tags, ticks, fills, tick,
-                            outst, off_n, mrg_n, hit_n)
-
+            # One loop serves a load's L1 lookups and a store's writes.
+            def blk(j, c):
                 (done, ctrl_free, tags, ticks, fills, tick, outst,
-                 off_n, mrg_n, hit_n) = lax.fori_loop(
-                    0, n_blk, blk,
-                    (add(t_acc, hit_lat), ctrl_free, tags, ticks, fills,
-                     tickc[sm], outst, off_n, mrg_n, hit_n))
-                tickc2 = tickc.at[sm].set(tick)
-                return (done, ctrl_free, tags, ticks, fills, tickc2,
+                 off_n, mrg_n, hit_n) = c
+                in_op = j < n_blk
+                ld = is_load & in_op
+                bi = o + j
+                b_slot = slot_col[bi]
+                b_ctrl = ctrl_col[bi]
+                b_si = si_col[bi]
+                # L1 lookup (pending lines visible with fill time);
+                # every lookup is one LRU touch tick.
+                tick = tick + ld.astype(i64)
+                line = (sm * n_sets_pad + b_si) * ways_pad
+                row = lax.dynamic_slice(tags, (line,), (ways_pad,))
+                match = (row == b_slot) & way_mask
+                present = jnp.any(match)
+                widx = line + jnp.argmax(match)
+                fill = fills[widx]
+                ticks = ticks.at[widx].set(
+                    jnp.where(ld & present, tick, ticks[widx]))
+                is_hit = ld & present & (fill <= t_acc)
+                oidx = sm * n_slots_pad + b_slot
+                out = outst[oidx]
+                is_merge = ld & (~is_hit) & ideal & (out > t_acc)
+                do_dram = ld & (~is_hit) & (~is_merge)
+                # DRAM request (full 64 B read transaction), or a store's
+                # write of its bytes.
+                cf = ctrl_free[b_ctrl]
+                start = jnp.maximum(cf, t_acc)
+                completion = add(add(start, dram_lat), svc_unit)
+                ctrl_free = ctrl_free.at[b_ctrl].set(jnp.where(
+                    do_dram, add(start, svc_unit),
+                    jnp.where(is_store & in_op, add(start, ssvc_col[bi]),
+                              cf)))
+                # L1 fill / pending-line allocation.
+                tick = tick + do_dram.astype(i64)
+                valid = (row != -1) & way_mask
+                empties = (~valid) & way_mask
+                has_empty = jnp.any(empties)
+                tick_row = lax.dynamic_slice(ticks, (line,), (ways_pad,))
+                victim = jnp.argmin(
+                    jnp.where(valid, tick_row, tick_inf))  # LRU
+                ins_way = line + jnp.where(
+                    has_empty, jnp.argmax(empties), victim)
+                upd_way = jnp.where(present, widx, ins_way)
+                tags = tags.at[ins_way].set(
+                    jnp.where(do_dram & (~present), b_slot, tags[ins_way]))
+                ticks = ticks.at[upd_way].set(
+                    jnp.where(do_dram, tick, ticks[upd_way]))
+                new_fill = jnp.where(
+                    present, jnp.minimum(fill, completion), completion)
+                fills = fills.at[upd_way].set(
+                    jnp.where(do_dram, new_fill, fills[upd_way]))
+                outst = outst.at[oidx].set(
+                    jnp.where(do_dram & ideal, completion, out))
+                off_n = off_n + do_dram.astype(i64)
+                mrg_n = mrg_n + is_merge.astype(i64)
+                hit_n = hit_n + is_hit.astype(i64)
+                done = jnp.where(is_merge, jnp.maximum(done, out), done)
+                done = jnp.where(do_dram,
+                                 jnp.maximum(done, completion), done)
+                return (done, ctrl_free, tags, ticks, fills, tick,
                         outst, off_n, mrg_n, hit_n)
 
-            def store_op(s):
-                (ctrl_free, tags, ticks, fills, tickc, outst,
-                 off_n, mrg_n, hit_n) = s
-
-                def blk(j, cfree):
-                    bi = o + j
-                    cf = cfree[ctrl_col[bi]]
-                    start = jnp.maximum(cf, t_acc)
-                    return cfree.at[ctrl_col[bi]].set(
-                        add(start, ssvc_col[bi]))
-
-                ctrl_free = lax.fori_loop(0, n_blk, blk, ctrl_free)
-                return (add(t_acc, hit_lat), ctrl_free, tags, ticks, fills,
-                        tickc, outst, off_n + n_blk, mrg_n, hit_n)
-
-            (warp_ready, ctrl_free, tags, ticks, fills, tickc, outst,
-             off_n, mrg_n, hit_n) = lax.switch(
-                kind_col[i], (compute_op, load_op, store_op), op_state)
+            trips = lax.pmax(jnp.where(is_load | is_store, n_blk, 0), _UNITS)
+            (done, ctrl_free, tags, ticks, fills, tick, outst,
+             off_n, mrg_n, hit_n) = lax.fori_loop(
+                0, trips, blk,
+                (add(t_acc, hit_lat), ctrl_free, tags, ticks, fills,
+                 tickc[sm], outst, off_n, mrg_n, hit_n))
+            tickc = tickc.at[sm].set(tick)
+            off_n = off_n + jnp.where(is_store, n_blk, 0)
+            warp_ready = jnp.where(kind_col[i] == 0, add(t_acc, depth), done)
 
             ni = i + 1
-            ready = ready.at[w].set(
-                jnp.where(ni < op_end[w], warp_ready, INF))
-            next_idx = next_idx.at[w].set(ni)
+            ready = ready.at[w].set(jnp.where(
+                active, jnp.where(ni < op_end[w], warp_ready, INF),
+                ready[w]))
+            next_idx = next_idx.at[w].set(jnp.where(active, ni, i))
             return (ready, next_idx, issue_free, ctrl_free, tags, ticks,
-                    fills, tickc, outst, off_n, mrg_n, hit_n)
+                    fills, tickc, outst, off_n, mrg_n, hit_n,
+                    steps + 1 + trips)
 
         final = lax.while_loop(cond, body, state0)
         issue_free = final[2]
-        return (jnp.max(issue_free), final[9], final[10], final[11])
+        return (jnp.max(issue_free), final[9], final[10], final[11],
+                final[12])
 
-    return jax.jit(jax.vmap(_simulate_one))
+    return jax.jit(jax.vmap(_simulate_one, axis_name=_UNITS))
 
 
 # ---------------------------------------------------------------------------
@@ -449,6 +485,54 @@ def _bits(x) -> np.ndarray:
     return np.asarray(x, dtype=np.float64).view(np.int64)
 
 
+def _sm_of(n_warps: int, n_sms: int) -> np.ndarray:
+    """The SM of each warp: contiguous equal shares, in warp order."""
+    wids = np.arange(n_warps, dtype=np.int64)
+    return np.minimum(wids * n_sms // max(n_warps, 1), n_sms - 1)
+
+
+def _pow4(n: int) -> int:
+    """Smallest power of four >= max(n, 1)."""
+    p = 1
+    while p < n:
+        p *= 4
+    return p
+
+
+def _sm_slots(cols: dict, n_sms: int) -> Tuple[np.ndarray, int, int]:
+    """Each block's SW+ slot: its rank among the distinct blocks that its
+    own SM's warps touch (the ops of a warp own the contiguous block
+    slices ``blk_off:blk_off + blk_len``). Returns the slot column, the
+    most slots any SM needs and the stream's distinct blocks."""
+    blocks = cols["blocks"]
+    if not len(blocks):
+        return np.zeros(0, dtype=np.int64), 1, 1
+    op_sm = np.repeat(_sm_of(cols["n_warps"], n_sms),
+                      np.diff(cols["op_start"]))
+    lens = cols["blk_len"]
+    owned = np.repeat(cols["blk_off"] - np.cumsum(lens) + lens, lens)
+    blk_sm = np.empty(len(blocks), dtype=np.int64)
+    blk_sm[owned + np.arange(len(owned))] = np.repeat(op_sm, lens)
+    uniq, dense = np.unique(blocks, return_inverse=True)
+    pairs, pair_id = np.unique(blk_sm * len(uniq) + dense,
+                               return_inverse=True)
+    # Pairs sort SM-major: an SM's slots count from its first pair.
+    pair_sm = pairs // len(uniq)
+    first = np.searchsorted(pair_sm, np.arange(n_sms))
+    slots = pair_id.astype(np.int64) - first[blk_sm]
+    return slots, int(np.bincount(pair_sm).max()), len(uniq)
+
+
+def _unit_state_bytes(dims: tuple, w_pad: int) -> int:
+    """Bytes of one unit's loop-carried state in bucket `dims`: ready and
+    next per warp; issue frees and tick counters per SM; controller
+    frees; L1 tags, ticks and fills; the SW+ outstanding table; three
+    counters. Every element is 8 bytes."""
+    n_sms, nctrl, n_sets, ways, n_slots = dims
+    return 8 * (2 * w_pad + 2 * n_sms + nctrl + 3 * n_sms * n_sets * ways
+                + n_sms * n_slots + 3)
+
+
 def pack_units(units: Sequence[Tuple[dict, dict]]) -> Tuple[tuple, dict]:
     """Pad and stack units = [(stream cols, machine scalars)].
 
@@ -468,23 +552,25 @@ def pack_units(units: Sequence[Tuple[dict, dict]]) -> Tuple[tuple, dict]:
             _pow2(max(s["n_sets"] for _, s in units)),
             _pow2(max(s["ways"] for _, s in units)))
 
-    # SW+ outstanding table: dense over the unique blocks of each stream.
-    # Cache the remap per stream object — variants share their expansion.
+    # SW+ outstanding table: dense over each SM's own distinct blocks.
+    # Cache the remap per stream object and SM count — variants share
+    # their expansion. Its width pads to a power of four, never past the
+    # power of two of the distinct blocks (what one table over every SM
+    # would need): a benchmark's per-SM count wanders across seeds (BFS
+    # at 2 SMs, 1543 to 2271), and the coarser ladder keeps its seeds in
+    # one compiled program.
     slot_cache: dict = {}
 
-    def slots_of(cols):
-        key = id(cols["blocks"])
+    def slots_of(cols, n_sms):
+        key = (id(cols["blocks"]), n_sms)
         hit = slot_cache.get(key)
         if hit is None:
-            _, inv = np.unique(cols["blocks"], return_inverse=True)
-            hit = slot_cache[key] = inv.astype(np.int64)
+            hit = slot_cache[key] = _sm_slots(cols, n_sms)
         return hit
 
-    n_slots = 1
-    for cols, _ in units:
-        s = slots_of(cols)
-        n_slots = max(n_slots, int(s.max(initial=0)) + 1)
-    dims += (_pow2(n_slots),)
+    counts = [slots_of(c, s["num_sms"])[1:] for c, s in units]
+    dims += (min(_pow4(max(n for n, _ in counts)),
+                 _pow2(max(d for _, d in counts))),)
 
     def stack(width, fill=0):
         return np.full((u_pad, width), fill, dtype=np.int64)
@@ -503,9 +589,7 @@ def pack_units(units: Sequence[Tuple[dict, dict]]) -> Tuple[tuple, dict]:
         n_sms = scal["num_sms"]
         st["next0"][u, :nw] = cols["op_start"][:nw]
         st["end"][u, :nw] = cols["op_start"][1:nw + 1]
-        wids = np.arange(nw, dtype=np.int64)
-        st["sm_of"][u, :nw] = np.minimum(wids * n_sms // max(nw, 1),
-                                         n_sms - 1)
+        st["sm_of"][u, :nw] = _sm_of(nw, n_sms)
         no = len(cols["issue"])
         st["issue"][u, :no] = _bits(cols["issue"])
         st["kind"][u, :no] = cols["kind"]
@@ -513,7 +597,7 @@ def pack_units(units: Sequence[Tuple[dict, dict]]) -> Tuple[tuple, dict]:
         st["len"][u, :no] = cols["blk_len"]
         nb = len(cols["blocks"])
         st["blocks"][u, :nb] = cols["blocks"]
-        st["slot"][u, :nb] = slots_of(cols)
+        st["slot"][u, :nb] = slots_of(cols, n_sms)[0]
         # Minimum 32 B burst, exactly the host expression.
         st["ssvc"][u, :nb] = _bits(
             scal["svc_unit"] * (np.maximum(cols["nbytes"], 32) / 64.0))
@@ -537,6 +621,8 @@ def _launch_units(units: Sequence[Tuple[dict, dict]],
     the ``pallas_pack`` stage (packing and the program lookup), one
     ``device`` occupancy hold (``device_inflight`` / ``device_queued``)
     and, inside it, the ``pallas_dispatch`` stage (the jit call alone).
+    A counted launch adds its loop steps and state bytes to the module's
+    recent launches and to the ambient obs domain's metrics.
     """
     global LAUNCHES
     jax, _jnp, _lax = _require_modules()
@@ -548,10 +634,22 @@ def _launch_units(units: Sequence[Tuple[dict, dict]],
     with obs_mod.occupancy("device", units=len(units)), jax.enable_x64(True):
         with obs_mod.stage("pallas_dispatch"):
             out = launch(stacked)
-        cycles, offchip, merged, hits = jax.device_get(out)
+        cycles, offchip, merged, hits, steps = jax.device_get(out)
     if count_launch:
+        steps = int(np.max(steps))
+        state = _unit_state_bytes(dims, stacked["next0"].shape[1])
+        registry = obs_mod.current_obs().registry
         with _LAUNCH_LOCK:
             LAUNCHES += 1
+            _RECENT.append((steps, state))
+            registry.counter(
+                "warpsim_pallas_loop_steps_total",
+                "device loop steps: each launch's outer steps plus inner "
+                "trips").inc(steps)
+            gauge = registry.gauge(
+                "warpsim_pallas_state_bytes",
+                "largest loop-carried state of one unit of a launch, bytes")
+            gauge.set(max(gauge.value, state))
     cycles = np.asarray(cycles, dtype=np.int64).view(np.float64)
     return [(float(cycles[u]), int(offchip[u]), int(merged[u]),
              int(hits[u])) for u in range(len(units))]

@@ -4,9 +4,12 @@ Mirrors Table 1 of the paper (GPGPU-sim 2.1.1b baseline): 16 SMs, 8-wide
 SIMD, 24-stage pipeline, 1024 thread contexts per SM, 64 B cache blocks /
 memory-transaction strides, 6 memory controllers at 76.8 GB/s aggregate.
 
-The simulator scales the SM count down (SMs are homogeneous and the paper's
-benchmarks fill them symmetrically); DRAM bandwidth is scaled with it so
-per-SM memory pressure is preserved.
+``num_sms`` defaults to 2, a scale-down of Table 1's 16 (SMs are
+homogeneous and the paper's benchmarks fill them symmetrically);
+``machines.paper_suite(num_sms=16)`` is the machine at its published size.
+Each simulated SM keeps its 1/16 share of the DRAM bandwidth, and a
+study's thread pool follows the SM count (``sweep.resolve_n_threads``),
+so per-SM occupancy and memory pressure are the same at any SM count.
 """
 
 from __future__ import annotations
@@ -31,7 +34,7 @@ class MachineConfig:
     mimd: bool = False
 
     # --- core ---
-    num_sms: int = 2                  # scaled from 16 (homogeneous SMs)
+    num_sms: int = 2                  # Table 1: 16 (homogeneous SMs)
     threads_per_sm: int = 1024
     pipeline_depth: int = 24          # cycles before a warp's next dependent issue
     core_clock_ghz: float = 1.3

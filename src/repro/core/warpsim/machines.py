@@ -27,11 +27,14 @@ def lw_plus(simd_width: int = 8, **kw) -> MachineConfig:
         mimd=True, **kw)
 
 
-def paper_suite(simd_width: int = 8) -> Dict[str, MachineConfig]:
-    """The seven machines of Figures 5-7."""
-    suite = {f"ws{w}": baseline(w, simd_width) for w in (8, 16, 32, 64)}
-    suite["SW+"] = sw_plus(simd_width)
-    suite["LW+"] = lw_plus(simd_width)
+def paper_suite(simd_width: int = 8, num_sms: int = 2
+                ) -> Dict[str, MachineConfig]:
+    """The six machines of Figures 5-7, on `num_sms` SMs: 2 by default,
+    Table 1's 16 for the machine at its published size."""
+    suite = {f"ws{w}": baseline(w, simd_width, num_sms=num_sms)
+             for w in (8, 16, 32, 64)}
+    suite["SW+"] = sw_plus(simd_width, num_sms=num_sms)
+    suite["LW+"] = lw_plus(simd_width, num_sms=num_sms)
     return suite
 
 

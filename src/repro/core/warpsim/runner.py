@@ -19,7 +19,8 @@ from repro.core.warpsim.trace import BENCHMARKS, get_workload
 
 def run_one(bench: str, cfg: MachineConfig, n_threads: Optional[int] = None,
             seed: int = 0, engine: str = "auto") -> SimResult:
-    wl = get_workload(bench, n_threads=n_threads, seed=seed)
+    wl = get_workload(bench, seed=seed, n_threads=sweep_mod.resolve_n_threads(
+        bench, cfg, n_threads))
     stream = expand_stream(wl, cfg)
     return simulate(wl.name, stream, cfg, engine=engine)
 

@@ -96,7 +96,7 @@ from repro.core.warpsim.timing import (
     SimResult, loop_result, simulate, stream_totals,
 )
 from repro.core.warpsim.trace import (
-    BENCHMARKS, ThreadTrace, Workload, get_workload,
+    BENCHMARKS, POOL_SMS, ThreadTrace, Workload, get_workload,
 )
 
 # Bump whenever the simulation model changes observable numbers: it is part
@@ -141,6 +141,18 @@ def _default_n_threads(bench: str) -> int:
     return get_workload(bench).n_threads
 
 
+def resolve_n_threads(bench: str, cfg: MachineConfig,
+                      n_threads: Optional[int]) -> int:
+    """The thread count one cell simulates: `n_threads` when given, else
+    the benchmark's pool scaled to the machine's SMs. The pools are sized
+    for :data:`~repro.core.warpsim.trace.POOL_SMS` SMs, so Table 1's
+    16-SM machine runs 8x the pool (16,384 threads of BFS) and a 2-SM
+    machine the pool itself."""
+    if n_threads is not None:
+        return n_threads
+    return _default_n_threads(bench.upper()) * cfg.num_sms // POOL_SMS
+
+
 @functools.lru_cache(maxsize=256)
 def _machine_dict(cfg: MachineConfig) -> dict:
     """Memoized ``dataclasses.asdict`` (MachineConfig is frozen/hashable;
@@ -156,10 +168,9 @@ def cell_key(bench: str, cfg: MachineConfig, n_threads: Optional[int],
     (including PR 1's sharded layout) stay valid, so changes here require
     a MODEL_VERSION bump.
     """
-    if n_threads is None:
-        # Canonicalize: a cell run with the bench's default thread count is
-        # the same cell as one requesting that count explicitly.
-        n_threads = _default_n_threads(bench.upper())
+    # Canonicalize: a cell run with the default thread count is the same
+    # cell as one requesting that count explicitly.
+    n_threads = resolve_n_threads(bench, cfg, n_threads)
     blob = json.dumps({
         "model": MODEL_VERSION,
         "bench": bench.upper(),
@@ -320,20 +331,20 @@ class ResultCache:
         if not self._root_ok:
             os.makedirs(self.root, exist_ok=True)
             self._root_ok = True
-        # Direct low-level write, no tmp+rename dance: a torn write (crash
-        # mid-put, or two processes racing on one cell) leaves a file the
-        # corruption-recovery path in get() detects, deletes and
-        # re-simulates — and results are deterministic, so losing a racer's
-        # copy costs a re-simulation, never wrong data. The rename barely
-        # bought safety but doubled the syscall bill of cold sweeps.
+        # Written under a name of the writer's own, then renamed into
+        # place, so that no reader sees a cell half written: get() would
+        # take it for a corrupt entry and quarantine it, and the cell
+        # would be simulated again.
         data = json.dumps({"key": key, "model": MODEL_VERSION,
                            "result": dataclasses.asdict(result)}).encode()
-        fd = os.open(self._path(key),
-                     os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o644)
+        path = self._path(key)
+        tmp = f"{path}.{os.getpid()}.{threading.get_ident()}.tmp"
+        fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o644)
         try:
             os.write(fd, data)
         finally:
             os.close(fd)
+        os.replace(tmp, path)
         name = key + ".json"
         with self._lock:
             self._legacy.pop(name, None)  # flat copy supersedes a legacy one
@@ -616,8 +627,9 @@ def __getattr__(name: str):
 # ---------------------------------------------------------------------------
 
 
-# One grid cell: (machine name, machine config, bench, n_threads, seed).
-Cell = Tuple[str, MachineConfig, str, Optional[int], int]
+# One grid cell: (machine name, machine config, bench, n_threads, seed),
+# its thread count resolved (:func:`resolve_n_threads`).
+Cell = Tuple[str, MachineConfig, str, int, int]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -663,13 +675,17 @@ class SweepSpec:
 
         Pass a precomputed ``machine_set()`` to avoid rebuilding it (the
         result is identical; ``run_sweep`` computes the set exactly once).
+        Each cell carries its resolved thread count, so its cell key,
+        trace key and trace family follow its machine's SM count: a study
+        of 2-SM and 16-SM machines is two families a benchmark and seed.
         """
         mset = self.machine_set() if machine_set is None else machine_set
         out: List[Cell] = []
         for mname, cfg in mset.items():
             for b in self.benches:
+                n_threads = resolve_n_threads(b, cfg, self.n_threads)
                 for seed in self.seeds:
-                    out.append((mname, cfg, b, self.n_threads, seed))
+                    out.append((mname, cfg, b, n_threads, seed))
         return out
 
 
@@ -893,7 +909,8 @@ def compute_cell(bench: str, cfg: MachineConfig,
     """
     tcache = TRACE_CACHE if trace_cache is None else trace_cache
     ecache = EXPANSION_CACHE if expansion_cache is None else expansion_cache
-    wl = get_workload(bench, n_threads=n_threads, seed=seed)
+    wl = get_workload(bench, seed=seed,
+                      n_threads=resolve_n_threads(bench, cfg, n_threads))
     with obs_mod.stage("aggregate", bench=bench):
         stream = ecache.get(
             wl, cfg, trace_fn=lambda: _traced_build(tcache, wl, trace_dir))

@@ -173,11 +173,17 @@ class ThreadTrace:
                                       self.addr_vals))
 
 
+# SMs of the machine each benchmark's default thread pool fills (1024 or
+# 512 threads an SM); a machine of other size runs the pool scaled to its
+# SM count (``sweep.resolve_n_threads``).
+POOL_SMS = 2
+
+
 @dataclasses.dataclass(frozen=True)
 class Workload:
     name: str
     program: Sequence[Stmt]
-    n_threads: int = 2048           # per simulated SM pool (scaled)
+    n_threads: int = 2048           # pool of a POOL_SMS-SM machine
     seed: int = 0
     # Relative weight used when averaging across the suite (all equal).
     description: str = ""
@@ -210,7 +216,7 @@ def correlated_outcomes(
 
 
 # --------------------------------------------------------------------------
-# The 15 paper benchmarks (Table 2), scaled
+# The 15 paper benchmarks (Table 2), their pools sized for POOL_SMS SMs
 # --------------------------------------------------------------------------
 
 

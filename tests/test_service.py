@@ -1037,6 +1037,60 @@ def test_overlapping_family_studies_and_reads_simulate_each_cell_once(
         assert dataclasses.asdict(res) == dataclasses.asdict(want[(m, seed)])
 
 
+def test_a_cell_read_while_its_owner_writes_it_is_simulated_once(
+        tmp_path, monkeypatch):
+    """A request that probes the cache while the owner of the cell is
+    writing it, midway through the write, waits on the owner instead of
+    taking the half-written entry for a corrupt one and quarantining it;
+    the cell lands whole and is simulated once."""
+    svc = SweepService(str(tmp_path), persist_traces=False)
+    cfg = machines.baseline(32)
+    writing, probed = threading.Event(), threading.Event()
+
+    class WriteHeldOpen:
+        """The cache's `os`, with the owner's writes held mid-way."""
+
+        def __getattr__(self, name):
+            return getattr(os, name)
+
+        @staticmethod
+        def write(fd, data):
+            if threading.current_thread().name == "owner":
+                os.write(fd, data[:len(data) // 2])
+                writing.set()
+                assert probed.wait(30)
+                return os.write(fd, data[len(data) // 2:])
+            return os.write(fd, data)
+
+    real_get = svc.cache.get
+
+    def get(key):
+        res = real_get(key)
+        if threading.current_thread().name == "reader":
+            probed.set()
+        return res
+
+    monkeypatch.setattr(sweep_mod, "os", WriteHeldOpen())
+    monkeypatch.setattr(svc.cache, "get", get)
+    got = {}
+
+    def read(name):
+        got[name] = svc.cell_with_source("BFS", cfg, 64, 0, engine="native")
+
+    owner = threading.Thread(target=read, args=("owner",), name="owner")
+    owner.start()
+    assert writing.wait(30)
+    reader = threading.Thread(target=read, args=("reader",), name="reader")
+    reader.start()
+    owner.join(30)
+    reader.join(30)
+    assert got["owner"][1] == "simulated" and got["reader"][1] == "dedup"
+    res, source = svc.cell_with_source("BFS", cfg, 64, 0, engine="native")
+    assert source == "cache" and svc.cache.corrupt == 0
+    assert svc.counters["simulated"] == 1
+    assert res == got["owner"][0] == got["reader"][0]
+
+
 @pallas_required
 def test_mesh_daemons_serving_one_family_never_wait_on_each_other(
         tmp_path, monkeypatch):
