@@ -304,7 +304,8 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
         svc_unit = cols["svc"][0]
         ways = cols["ways"][0]
 
-        way_mask = jnp.arange(ways_pad, dtype=i64) < ways
+        way_idx = jnp.arange(ways_pad, dtype=i64)
+        way_mask = way_idx < ways
         tick_inf = jnp.iinfo(i64).max
 
         ready0 = jnp.where(next0 < op_end, 0, INF).astype(i64)
@@ -369,7 +370,10 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
                 # every lookup is one LRU touch tick.
                 tick = tick + ld.astype(i64)
                 line = (sm * n_sets_pad + b_si) * ways_pad
-                row = lax.dynamic_slice(tags, (line,), (ways_pad,))
+                # Rows are read by index gather: a vmapped dynamic_slice
+                # lowers on XLA:TPU to a serial loop over the units.
+                row_idx = line + way_idx
+                row = tags[row_idx]
                 match = (row == b_slot) & way_mask
                 present = jnp.any(match)
                 widx = line + jnp.argmax(match)
@@ -395,7 +399,7 @@ def _get_launch(n_sms_pad: int, nctrl_pad: int, n_sets_pad: int,
                 valid = (row != -1) & way_mask
                 empties = (~valid) & way_mask
                 has_empty = jnp.any(empties)
-                tick_row = lax.dynamic_slice(ticks, (line,), (ways_pad,))
+                tick_row = ticks[row_idx]
                 victim = jnp.argmin(
                     jnp.where(valid, tick_row, tick_inf))  # LRU
                 ins_way = line + jnp.where(

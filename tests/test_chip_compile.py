@@ -56,10 +56,10 @@ def _structs(tree, sharding):
         tree)
 
 
-def test_warpsim_family_program_compiles_integer_only(one_chip):
-    """The paper grid's NQU family (6 machines in one launch) compiles
-    for the chip with no 64-bit float anywhere: times travel as int64
-    bit patterns, since XLA:TPU's f64 is not IEEE."""
+@pytest.fixture(scope="module")
+def nqu_family_hlo(one_chip):
+    """Optimized HLO of the paper grid's NQU family (6 machines, 8 padded
+    units in one launch), compiled once for the described chip."""
     wl = get_workload("NQU")
     units = [(_pallas._stream_cols(expand_stream(wl, cfg)),
               _pallas._cfg_scalars(cfg))
@@ -68,11 +68,26 @@ def test_warpsim_family_program_compiles_integer_only(one_chip):
     with jax.enable_x64(True):
         compiled = _pallas._get_launch(*dims).lower(
             _structs(stacked, one_chip)).compile()
-    hlo = compiled.as_text()
-    types = set(re.findall(r"\b([fsu]\d+)\[", hlo))
+    return compiled.as_text()
+
+
+def test_warpsim_family_program_compiles_integer_only(nqu_family_hlo):
+    """The NQU family compiles for the chip with no 64-bit float
+    anywhere: times travel as int64 bit patterns, since XLA:TPU's f64 is
+    not IEEE."""
+    types = set(re.findall(r"\b([fsu]\d+)\[", nqu_family_hlo))
     assert "s64" in types
     assert not {"f64", "f32", "f16", "bf16"} & types, types
-    assert "tpu_custom_call" not in hlo       # no kernel on this path
+    assert "tpu_custom_call" not in nqu_family_hlo   # no kernel on this path
+
+
+def test_warpsim_family_program_has_only_its_two_loops(nqu_family_hlo):
+    """The family program's only loops are the outer step and the inner
+    trip. A read of loop-carried state that XLA:TPU emits as a serial
+    loop over the launch's units (a vmapped ``dynamic_slice`` of an L1
+    row does) costs each inner trip one nested loop per u32 half."""
+    loops = re.findall(r"\swhile\(", nqu_family_hlo)
+    assert len(loops) == 2, len(loops)
 
 
 def test_family_program_keeps_the_name_the_benchmark_reads():

@@ -25,11 +25,11 @@ import pytest
 from repro.core.warpsim import _native, _pallas, machines, runner
 from repro.core.warpsim.config import MachineConfig
 from repro.core.warpsim.divergence import (
-    WarpStream, aggregate_stream, build_thread_trace, expand_stream,
-    expand_stream_single,
+    KIND_COMPUTE, KIND_LOAD, KIND_STORE, WarpStream, aggregate_stream,
+    build_thread_trace, expand_stream, expand_stream_single,
 )
 from repro.core.warpsim.sweep import expansion_key
-from repro.core.warpsim.timing import simulate
+from repro.core.warpsim.timing import loop_result, simulate, stream_totals
 from repro.core.warpsim.trace import (
     Branch, Compute, Loop, Mem, Workload, get_workload,
 )
@@ -79,6 +79,55 @@ def test_fast_engine_accepts_legacy_warp_ops(engine):
     from_stream = simulate(wl.name, stream, cfg, engine=engine)
     from_ops = simulate(wl.name, stream.to_warp_ops(), cfg, engine=engine)
     assert dataclasses.asdict(from_stream) == dataclasses.asdict(from_ops)
+
+
+def _hand_stream(warps) -> WarpStream:
+    """A WarpStream from per-warp ``[(kind, blocks), ...]``; every op
+    issues in 1 cycle with 8 lanes, every transaction touches 64 B."""
+    ops = [(w, kind, blocks) for w, wops in enumerate(warps)
+           for kind, blocks in wops]
+    lens = np.array([len(b) for _, _, b in ops], dtype=np.int64)
+    ones = np.ones(len(ops), dtype=np.int64)
+    blocks = np.concatenate([np.asarray(b, dtype=np.int64)
+                             for _, _, b in ops])
+    return WarpStream(
+        n_warps=len(warps),
+        warp=np.array([w for w, _, _ in ops], dtype=np.int64),
+        issue=ones, tins=8 * ones, lanes=8 * ones,
+        kind=np.array([k for _, k, _ in ops], dtype=np.int8),
+        maccs=8 * lens, blk_off=np.cumsum(lens) - lens, blk_len=lens,
+        blocks=blocks, nbytes=np.full(len(blocks), 64, dtype=np.int64),
+        op_start=np.cumsum([0] + [len(w) for w in warps]).astype(np.int64))
+
+
+@pytest.mark.skipif(not _pallas.available(), reason="jax does not import")
+def test_family_launch_reads_the_last_l1_row_like_the_event_loop():
+    """Two units in one launch whose loads land in the last set of the
+    last SM, i.e. the last row of the flat L1 tables. The 3-way unit pads
+    to 4 ways, so its row read has a masked way; it evicts its LRU line
+    where the 4-way unit still has room. Every field equals the event
+    loop's."""
+    ld, st = KIND_LOAD, KIND_STORE
+    stream = _hand_stream([
+        [(KIND_COMPUTE, []), (ld, [0, 4])],                # warp 0, SM 0
+        [(ld, [3, 7, 11]), (ld, [3]), (ld, [15]),          # warp 1, SM 1,
+         (ld, [7]), (st, [3])],                            # set 3
+    ])
+    cfgs = [MachineConfig(name="ways3", num_sms=2, l1_ways=3,
+                          l1_size_bytes=64 * 3 * 4),
+            MachineConfig(name="ways4.sw+", num_sms=2, l1_ways=4,
+                          l1_size_bytes=64 * 4 * 4, ideal_coalescing=True)]
+    units = [(_pallas._stream_cols(stream), _pallas._cfg_scalars(c))
+             for c in cfgs]
+    n_sms, _, n_sets, ways, _ = _pallas.pack_units(units)[0]
+    assert (n_sms, n_sets, ways) == (2, 4, 4)
+    event = [simulate("edge", stream, c, engine="event") for c in cfgs]
+    # The 3-way unit misses on block 7 after evicting it; the 4-way hits.
+    assert [e.l1_hits for e in event] == [1, 2]
+    loops = _pallas._launch_units(units, count_launch=False)
+    for cfg, loop, ref in zip(cfgs, loops, event):
+        got = loop_result("edge", cfg, loop, stream_totals(stream))
+        assert dataclasses.asdict(got) == dataclasses.asdict(ref), cfg.name
 
 
 # ------------------------------------------------------------ expansion key
